@@ -19,6 +19,8 @@ from repro.core import (available_methods, bilevel_l1inf, project_l1,
                         project_l1inf_exact, multilevel_project,
                         trilevel_l111, trilevel_l1infinf)
 
+from .devices import run_cpu_mesh_child
+
 
 def _time(fn, *args, reps=5, warmup=2):
     for _ in range(warmup):
@@ -201,9 +203,6 @@ def plan_sweep(full=False):
 
 
 _SHARDED_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import json, sys, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -221,7 +220,8 @@ def _time(fn, *args, reps=5, warmup=2):
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts)) * 1e6
 
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("model",))
 n, m = (1000, 10000) if FULL else (256, 2048)
 d = 32 if FULL else 8
 designs = [
@@ -254,7 +254,6 @@ for name, shape, levels, spec in designs:
                  f"coll_bytes={cb['gather_bytes']},shape={shape}"])
     per = ";".join(f"{s['step']}:{s['bytes']}" for s in cb["per_step"])
     rows.append([f"sharded_bytes_{name}", float(cb["schedule_bytes"]), per])
-print("ROWS" + json.dumps(rows))
 """
 
 
@@ -270,33 +269,20 @@ def sharded_sweep(full=False):
     breakdown in ``derived``.
     """
     import json as _json
-    import os
-    import subprocess
-    import sys as _sys
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("PYTHONPATH", "src")
-    res = subprocess.run(
-        [_sys.executable, "-c", _SHARDED_CHILD, _json.dumps(bool(full))],
-        capture_output=True, text=True, timeout=1200, env=env)
-    if res.returncode != 0:
-        raise RuntimeError(f"sharded sweep failed:\n{res.stderr[-3000:]}")
-    payload = res.stdout.split("ROWS", 1)[1]
-    return [(name, us, derived) for name, us, derived in _json.loads(payload)]
+    return run_cpu_mesh_child(_SHARDED_CHILD, _json.dumps(bool(full)),
+                              timeout=1200)
 
 
 _SHARDED_CODEGEN_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import json, sys, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import multilevel_project_sharded, plan as planmod
 
 FULL = json.loads(sys.argv[1])
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("model",))
 n, m = (1000, 10000) if FULL else (128, 1024)
 d = 8
 designs = [
@@ -373,7 +359,6 @@ for attempt in range(2):
 rows.append([f"sharded_codegen_plan_auto_{name}", t_auto,
              f"winner={p.method},best_fixed={best_name},"
              f"auto_vs_best={t_auto / t_best:.3f}"])
-print("ROWS" + json.dumps(rows))
 """
 
 
@@ -396,22 +381,9 @@ def sharded_codegen_sweep(full=False):
       fixed block of the candidate grid.
     """
     import json as _json
-    import os
-    import subprocess
-    import sys as _sys
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("PYTHONPATH", "src")
-    res = subprocess.run(
-        [_sys.executable, "-c", _SHARDED_CODEGEN_CHILD,
-         _json.dumps(bool(full))],
-        capture_output=True, text=True, timeout=1800, env=env)
-    if res.returncode != 0:
-        raise RuntimeError(
-            f"sharded_codegen sweep failed:\n{res.stderr[-3000:]}")
-    payload = res.stdout.split("ROWS", 1)[1]
-    rows = [(name, us, derived) for name, us, derived in _json.loads(payload)]
+    rows = run_cpu_mesh_child(_SHARDED_CODEGEN_CHILD, _json.dumps(bool(full)),
+                              timeout=1800)
     return rows + blocktune_rows(full)
 
 

@@ -7,8 +7,10 @@ Prints ``name,us_per_call,derived`` CSV rows (µs medians, steady-state) and,
 unless ``--no-json``, writes one machine-readable ``BENCH_<section>.json`` per
 section into ``--json-dir`` (default: CWD) — the bench-trajectory artifacts CI
 uploads. Default sizes are scaled for the single-core container; --full uses
-the paper's sizes. Roofline/dry-run numbers live in experiments/ (they come
-from the AOT pipeline, not this driver).
+the paper's sizes. Each row records the device it ran on (``platform``,
+``device_kind``, count); the mesh sections run in a forced-CPU child process
+and record the child's device. Roofline/dry-run numbers live in experiments/
+(they come from the AOT pipeline, not this script).
 """
 
 from __future__ import annotations
@@ -18,6 +20,10 @@ import json
 import pathlib
 import platform
 import sys
+
+from repro.runtime import compile_cache
+
+from .devices import device_record
 
 # artifact file names per section (the methods sweep seeds the trajectory)
 _JSON_NAMES = {
@@ -40,16 +46,18 @@ _JSON_NAMES = {
 
 
 def _write_json(json_dir: pathlib.Path, section: str, rows, full: bool) -> None:
-    import jax
-
+    # a row measured in a CPU-mesh child carries the child's own device
+    # record as a 4th element; every other row ran on this process's device
+    device = device_record()
     payload = {
         "section": section,
         "full": full,
-        "platform": jax.devices()[0].platform,
+        "device": device,
         "machine": platform.machine(),
         "rows": [
-            {"name": name, "us_per_call": round(us, 1), "derived": derived}
-            for name, us, derived in rows
+            {"name": name, "us_per_call": round(us, 1), "derived": derived,
+             "device": dev[0] if dev else device}
+            for name, us, derived, *dev in rows
         ],
     }
     path = json_dir / _JSON_NAMES[section]
@@ -78,6 +86,7 @@ def main(argv=None) -> None:
                     help="CSV to stdout only, no artifact files")
     args = ap.parse_args(argv)
     only = set(filter(None, args.only.split(",")))
+    compile_cache.configure()
 
     from . import (obs_overhead, projections, sae_factory, sae_tables,
                    serving_trace, train_step)
@@ -111,7 +120,7 @@ def main(argv=None) -> None:
         if only and key not in only:
             continue
         rows = fn()
-        for name, us, derived in rows:
+        for name, us, derived, *_ in rows:
             print(f"{name},{us:.1f},{derived}")
             sys.stdout.flush()
         if not args.no_json:

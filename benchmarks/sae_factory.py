@@ -18,19 +18,16 @@ Four row groups into ``BENCH_sae_factory.json``:
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import tempfile
 import time
 
 from repro.data import classification_synthetic, lung_like
 
+from .devices import run_cpu_mesh_child
 from .sae_tables import run_dataset
 
 _GSP_CHILD = r"""
-import json, sys, time
+import sys, time
 import jax
 from repro.launch.mesh import make_host_mesh
 from repro.training import sae_factory as F
@@ -40,11 +37,11 @@ mesh = make_host_mesh(1, 8)
 t0 = time.perf_counter()
 g = F.gsp_whole_network(mesh=mesh, steps=int(sys.argv[1]))
 dt = time.perf_counter() - t0
-print("ROWS" + json.dumps([[
+rows = [[
     "sae_factory_gsp_8dev", dt * 1e6 / int(sys.argv[1]),
     f"nproj={g['n_projected']}_feasible={int(g['feasible'])}"
     f"_colsparsity={g['mean_col_sparsity']:.1f}%_ndev={g['n_devices']}",
-]]))
+]]
 """
 
 
@@ -102,17 +99,7 @@ def _factory_rows(full):
 
 
 def _gsp_row(full):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("PYTHONPATH", "src")
-    steps = 4 if full else 2
-    res = subprocess.run(
-        [sys.executable, "-c", _GSP_CHILD, str(steps)],
-        capture_output=True, text=True, timeout=1200, env=env)
-    if res.returncode != 0:
-        raise RuntimeError(f"gsp subprocess failed:\n{res.stderr[-3000:]}")
-    payload = res.stdout.split("ROWS", 1)[1]
-    return [(name, us, derived) for name, us, derived in json.loads(payload)]
+    return run_cpu_mesh_child(_GSP_CHILD, str(4 if full else 2), timeout=1200)
 
 
 def factory_sweep(full=False):

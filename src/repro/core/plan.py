@@ -114,6 +114,7 @@ _SPECIALIZED: Dict[str, PlanBackend] = {}
 _EXECS: Dict[Tuple[PlanKey, str], _Executable] = {}
 _PLANS: Dict[Tuple[PlanKey, str], "ProjectionPlan"] = {}
 _AUTO_WINNERS: Dict[PlanKey, Tuple[str, Dict[str, float]]] = {}
+_GENERIC_WINNERS: Dict[PlanKey, Tuple[str, Dict[str, float]]] = {}  # best_l1_method
 _MESHES: Dict[Tuple[Tuple[str, int], ...], object] = {}  # ShardingKey.mesh_axes -> Mesh
 _KERNEL_BACKENDS_LOADED = False
 
@@ -145,10 +146,12 @@ def clear_cache() -> None:
     hit/miss/retrace/autotune counters restart at zero so ``cache_info()``
     sizes and counters always describe the same cache generation.
     """
-    _EVICTIONS[0] += len(_PLANS) + len(_EXECS) + len(_AUTO_WINNERS)
+    _EVICTIONS[0] += (len(_PLANS) + len(_EXECS) + len(_AUTO_WINNERS)
+                      + len(_GENERIC_WINNERS))
     _EXECS.clear()
     _PLANS.clear()
     _AUTO_WINNERS.clear()
+    _GENERIC_WINNERS.clear()
     _COUNTERS.update(dict.fromkeys(_COUNTER_KEYS, 0))
 
 
@@ -166,7 +169,8 @@ def cache_info() -> Dict[str, int]:
     ``plan_cache_<name>`` gauges on every call.
     """
     info = {"plans": len(_PLANS), "executables": len(_EXECS),
-            "auto_winners": len(_AUTO_WINNERS), **_COUNTERS,
+            "auto_winners": len(_AUTO_WINNERS) + len(_GENERIC_WINNERS),
+            **_COUNTERS,
             "evictions": _EVICTIONS[0]}
     try:
         from repro.obs import metrics as _obs_metrics
@@ -290,11 +294,8 @@ def _maybe_register_kernel_backends() -> None:
     global _KERNEL_BACKENDS_LOADED
     if _KERNEL_BACKENDS_LOADED:
         return
+    from repro.kernels import plan_backends  # noqa: F401  (registers on import)
     _KERNEL_BACKENDS_LOADED = True
-    try:
-        from repro.kernels import plan_backends  # noqa: F401  (registers on import)
-    except Exception:  # pragma: no cover - jax without pallas support
-        pass
 
 
 def _backend_available(backend: PlanBackend, key: PlanKey) -> bool:
@@ -410,8 +411,10 @@ def _grad_fn(key: PlanKey, name: str) -> Callable:
     return jax.jit(jax.value_and_grad(loss))
 
 
-def _autotune(key: PlanKey) -> Tuple[str, Dict[str, float]]:
-    """Interleaved min-of-rounds shoot-out over every candidate backend.
+def _autotune(key: PlanKey, names: Optional[List[str]] = None
+              ) -> Tuple[str, Dict[str, float]]:
+    """Interleaved min-of-rounds shoot-out over ``names`` (default: every
+    candidate backend of the key).
 
     Candidates are timed round-robin (not each in its own block) and the
     minimum per candidate is kept: the fastest rep is the least contaminated
@@ -424,11 +427,12 @@ def _autotune(key: PlanKey) -> Tuple[str, Dict[str, float]]:
     projection differentiated through by training.
     """
     y, radius = _bench_args(key)
+    if names is None:
+        names = _candidates(key)
     if key.grad:
-        fns = {name: _grad_fn(key, name) for name in _candidates(key)}
+        fns = {name: _grad_fn(key, name) for name in names}
     else:
-        fns = {name: _get_executable(key, name).fn
-               for name in _candidates(key)}
+        fns = {name: _get_executable(key, name).fn for name in names}
     for fn in fns.values():
         for _ in range(2):
             jax.block_until_ready(fn(y, radius))  # compile + warm
@@ -610,9 +614,19 @@ def best_l1_method(n: int, dtype=jnp.float32, *, device: str | None = None,
     times each θ-solver under ``value_and_grad`` (solvers differ much more in
     backward cost than forward: sort-based ones backprop through the sort).
     """
-    plan = make_plan((int(n),), dtype, [("1", 1)], method=AUTO, device=device,
-                     grad=grad)
-    return plan.method
+    _maybe_register_kernel_backends()
+    if device is None:
+        device = jax.devices()[0].platform
+    key = PlanKey((int(n),), np.dtype(dtype).name, canonical_levels([("1", 1)]),
+                  "scalar", device, False, None, bool(grad))
+    if key in _GENERIC_WINNERS:
+        _count("autotune_hits")
+    else:
+        # the specialized backends (the codegen kernels on TPU) are not
+        # θ-solvers a caller can embed: keep them out of this shoot-out
+        _count("autotune_runs")
+        _GENERIC_WINNERS[key] = _autotune(key, list(ball.available_methods()))
+    return _GENERIC_WINNERS[key][0]
 
 
 def maybe_plan_call(y, levels, radius):

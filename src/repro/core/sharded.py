@@ -35,11 +35,6 @@ from repro.obs import profile as obs_profile
 from . import ball
 from . import schedule as sched_mod
 
-try:  # jax >= 0.5 exports it at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
-
 _BISECT_ITERS = 64
 
 
@@ -240,14 +235,16 @@ def multilevel_project_sharded(y: jax.Array, levels, radius, *, mesh, spec,
     else:
         body = make_schedule_body(sched, names, method=meth)
     in_spec = P(*names)
-    # check_rep=False: the generic θ-solvers run while/fori loops (filter's
-    # active-set sweep, bisect's fixed iteration) that the replication checker
-    # has no rules for — it rejects them even though every carry is in fact
-    # uniformly replicated after the gather. Correctness is pinned by the
-    # sharded-vs-single-device equality tests across all registered methods.
-    out = shard_map(body, mesh=mesh, in_specs=(in_spec, P()),
-                    out_specs=in_spec,
-                    check_rep=False)(padded, jnp.asarray(radius, y.dtype))
+    # check_vma=False: the replicated outer solve runs the generic θ-solvers'
+    # while/fori loops (filter's active-set sweep, bisect's fixed iteration)
+    # on the gathered aggregate, and the Pallas kernels of the codegen body
+    # carry no varying-manual-axes annotations, so the checker cannot type
+    # them although every carry is in fact uniformly replicated after the
+    # gather. Correctness is pinned by the sharded-vs-single-device equality
+    # tests across all registered methods.
+    out = jax.shard_map(body, mesh=mesh, in_specs=(in_spec, P()),
+                        out_specs=in_spec,
+                        check_vma=False)(padded, jnp.asarray(radius, y.dtype))
     if out.shape != y.shape:
         out = out[tuple(slice(0, d) for d in y.shape)]
     return out
@@ -264,9 +261,9 @@ def bilevel_project_sharded(y_local: jax.Array, radius, p=1, q=jnp.inf,
     shard, columns sharded over ``axis_name``. Wrapper over the schedule body
     for ν = [(q, 1), (p, 1)]; requires even shards (the full-array
     ``multilevel_project_sharded`` pads uneven ones). The filter/bisect
-    θ-solvers need the enclosing shard_map built with ``check_rep=False``
-    (their while/fori loops have no replication rules — the executor does
-    this for you)."""
+    θ-solvers need the enclosing shard_map built with ``check_vma=False``
+    (their while/fori loops carry values the varying-manual-axes checker
+    cannot type — the executor does this for you)."""
     sched = sched_mod.compile_schedule(y_local.shape, [(q, 1), (p, 1)])
     body = make_schedule_body(sched, (None, axis_name), method=method)
     return body(y_local, radius)

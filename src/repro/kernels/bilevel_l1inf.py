@@ -30,8 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_N = 256   # rows per tile (sublane axis)
 DEFAULT_BLOCK_M = 512   # cols per tile (lane axis)
@@ -92,7 +92,7 @@ def colmax_pallas(y: jax.Array, *, block_n: int = DEFAULT_BLOCK_N,
         in_specs=[pl.BlockSpec((block_n, block_m), lambda j, i: (i, j))],
         out_specs=pl.BlockSpec((1, block_m), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, m), y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -117,7 +117,7 @@ def clip_pallas(y: jax.Array, u: jax.Array, *, block_n: int = DEFAULT_BLOCK_N,
         ],
         out_specs=pl.BlockSpec((block_n, block_m), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, m), y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

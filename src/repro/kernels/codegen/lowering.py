@@ -44,12 +44,12 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ball, schedule as sched_mod
 from repro.core.schedule import Schedule
 from repro.obs import profile as obs_profile
 
-from .._compat import CompilerParams
 from . import backward as bwd_mod
 from .tiling import TilePlan, plan_tiles
 
@@ -190,7 +190,7 @@ def _reduce_call(y: jax.Array, tp: TilePlan, norms: Sequence[str],
         in_specs=[_y_spec(tp)],
         out_specs=agg_specs + [_row_spec(tp)],
         out_shape=agg_shapes + [jax.ShapeDtypeStruct((1, tp.m), y.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -201,6 +201,15 @@ def _reduce_call(y: jax.Array, tp: TilePlan, norms: Sequence[str],
 # --------------------------------------------------------------------------- #
 # Apply epilogue
 # --------------------------------------------------------------------------- #
+
+
+def _f32(ref) -> jax.Array:
+    """Load a kernel operand for the apply epilogue's math, in float32.
+
+    The apply epilogues compute in f32 whatever the storage dtype and cast
+    on store: the chip's vector unit has no bf16 compare, which the clip /
+    rescale / θ-solve selects need (an f32 input is unchanged)."""
+    return ref[...].astype(jnp.float32)
 
 
 def _apply_chain(norms: Sequence[str], stages, w):
@@ -260,9 +269,10 @@ def _make_apply_kernel(norms: Sequence[str]):
         y_ref, v_refs = refs[0], refs[1:L - 1]
         vfin_ref = refs[L - 1] if has_vfin else None
         u_ref, out_ref = refs[-2], refs[-1]
-        stages = [y_ref[...]] + [v[...] for v in v_refs]  # s_0 … s_{L-2}
-        vfin = vfin_ref[...] if has_vfin else None
-        out_ref[...] = _apply_tile(norms, stages, vfin, u_ref[...])
+        stages = [_f32(y_ref)] + [_f32(v) for v in v_refs]  # s_0 … s_{L-2}
+        vfin = _f32(vfin_ref) if has_vfin else None
+        out_ref[...] = _apply_tile(norms, stages, vfin,
+                                   _f32(u_ref)).astype(out_ref.dtype)
 
     return kernel
 
@@ -280,7 +290,7 @@ def _apply_call(y: jax.Array, aggs, vfin: jax.Array, u: jax.Array,
                  + [_row_spec(tp)] * len(rows),
         out_specs=_y_spec(tp),
         out_shape=jax.ShapeDtypeStruct(tp.canon_shape, y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -300,8 +310,9 @@ def _make_partial_apply_kernel(norms: Sequence[str]):
     def kernel(*refs):
         y_ref, v_refs = refs[0], refs[1:L - 1]
         w_ref, out_ref = refs[-2], refs[-1]
-        stages = [y_ref[...]] + [v[...] for v in v_refs]
-        out_ref[...] = _apply_chain(norms, stages, w_ref[...])
+        stages = [_f32(y_ref)] + [_f32(v) for v in v_refs]
+        out_ref[...] = _apply_chain(norms, stages,
+                                    _f32(w_ref)).astype(out_ref.dtype)
 
     return kernel
 
@@ -318,7 +329,7 @@ def _partial_apply_call(y: jax.Array, aggs, w: jax.Array, tp: TilePlan,
         in_specs=[_y_spec(tp)] + agg_specs + [agg_specs[-1]],
         out_specs=_y_spec(tp),
         out_shape=jax.ShapeDtypeStruct(tp.canon_shape, y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -450,9 +461,9 @@ def generate(sched: Schedule, dtype, *, method: str = "bisect",
 # Items share no aggregates, so the batch axis never enters the schedule — it
 # becomes the LEADING (parallel) Pallas grid dimension instead of a vmap
 # around the batch-free kernel: one dispatch walks B × grid(base) programs,
-# per-item rows/radii are block-sliced by the batch grid index (radii ride in
-# SMEM for the θ-solve stage), and per-step VMEM residency stays the per-item
-# plan's.
+# per-item rows are block-sliced and radii indexed by the batch grid index
+# (radii ride in SMEM for the θ-solve stage), and per-step VMEM residency
+# stays the per-item plan's.
 
 
 def _y_spec_batched(tp: TilePlan):
@@ -521,7 +532,7 @@ def _reduce_call_batched(y: jax.Array, tp: TilePlan, norms: Sequence[str],
         out_specs=agg_specs + [_row_spec_batched(tp)],
         out_shape=agg_shapes
         + [jax.ShapeDtypeStruct((batch, 1, tp.m), y.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -538,9 +549,10 @@ def _make_batched_apply_kernel(norms: Sequence[str]):
         y_ref, v_refs = refs[0], refs[1:L - 1]
         vfin_ref = refs[L - 1] if has_vfin else None
         u_ref, out_ref = refs[-2], refs[-1]
-        stages = [y_ref[...][0]] + [v[...][0] for v in v_refs]
-        vfin = vfin_ref[...][0] if has_vfin else None
-        out_ref[...] = _apply_tile(norms, stages, vfin, u_ref[...][0])[None]
+        stages = [_f32(y_ref)[0]] + [_f32(v)[0] for v in v_refs]
+        vfin = _f32(vfin_ref)[0] if has_vfin else None
+        out_ref[...] = _apply_tile(norms, stages, vfin,
+                                   _f32(u_ref)[0])[None].astype(out_ref.dtype)
 
     return kernel
 
@@ -559,7 +571,7 @@ def _apply_call_batched(y: jax.Array, aggs, vfin: jax.Array, u: jax.Array,
                  + [_row_spec_batched(tp)] * len(rows),
         out_specs=_y_spec_batched(tp),
         out_shape=jax.ShapeDtypeStruct((batch,) + tp.canon_shape, y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

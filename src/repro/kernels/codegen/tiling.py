@@ -96,7 +96,7 @@ class BatchedTilePlan(NamedTuple):
     ``base`` is the per-item :class:`TilePlan`; the generated batched kernels
     prepend the batch extent as the LEADING (parallel) Pallas grid dimension,
     so one dispatch walks ``batch × grid(base)`` programs with per-item radii
-    block-sliced from SMEM by the batch grid index. Per-grid-step VMEM
+    indexed in SMEM by the batch grid index. Per-grid-step VMEM
     residency equals the per-item plan's (the batch block size is 1), so the
     budget check is the base plan's check.
     """

@@ -14,6 +14,10 @@ only elementwise ops + reductions. Single-block kernels: the whole (padded)
 vector lives in VMEM. That covers the aggregate vectors of every assigned
 architecture (d_ff ≤ 25600, experts ≤ 384, vocab ≤ 163840 → ≤ 640 KB f32).
 ``ops.py`` falls back to the jnp path for anything larger.
+
+The kernels compute in float32 whatever the input dtype: the radius reaches
+SMEM as a 32-bit scalar (the only scalar width Mosaic reads from SMEM), and
+the wrappers cast the result back to the input dtype.
 """
 
 from __future__ import annotations
@@ -25,10 +29,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 _ITERS = 64
 _LANE = 128
+
+
+# Both threshold kernels read their radius as ``radius_ref[program_id(0)]``:
+# the whole f32 radius vector sits in SMEM (a 1-element block of it is not a
+# tiling Mosaic accepts), one entry per program of the batch grid — entry 0
+# for the single-item call, whose grid is (1,).
 
 
 def _masked_abs(v_ref, n_total: int):
@@ -40,7 +49,7 @@ def _masked_abs(v_ref, n_total: int):
 
 def _l1ball_bisect_kernel(v_ref, radius_ref, out_ref, *, n_total: int, iters: int):
     v, a = _masked_abs(v_ref, n_total)
-    radius = radius_ref[0]
+    radius = radius_ref[pl.program_id(0)]
     inside = jnp.sum(a) <= radius
 
     def body(_, loh):
@@ -68,7 +77,7 @@ def _l1ball_filter_kernel(v_ref, radius_ref, out_ref, *, n_total: int, iters: in
     (termination is guaranteed in ≤ n sweeps; typical data needs < 10).
     """
     v, a = _masked_abs(v_ref, n_total)
-    radius = radius_ref[0]
+    radius = radius_ref[pl.program_id(0)]
     s0 = jnp.sum(a)
     inside = s0 <= radius
     theta0 = (s0 - radius) / n_total
@@ -135,8 +144,8 @@ def project_l1_pallas(v: jax.Array, radius, *, method: str = "bisect",
         # filter terminates in <= n sweeps; bisect needs its fixed budget
         iters = n + 2 if method == "filter" else _ITERS
     n_pad = max(_LANE, ((n + _LANE - 1) // _LANE) * _LANE)
-    v2 = jnp.zeros((1, n_pad), v.dtype).at[0, :n].set(v)
-    r = jnp.asarray(radius, v.dtype).reshape(1)
+    v2 = jnp.zeros((1, n_pad), jnp.float32).at[0, :n].set(v)
+    r = jnp.asarray(radius, jnp.float32).reshape(1)
     out = pl.pallas_call(
         functools.partial(_THRESHOLD_KERNELS[method], n_total=n, iters=iters),
         grid=(1,),
@@ -145,10 +154,10 @@ def project_l1_pallas(v: jax.Array, radius, *, method: str = "bisect",
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), v.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
     )(v2, r)
-    return out[0, :n]
+    return out[0, :n].astype(v.dtype)
 
 
 def project_l1_pallas_batched(v: jax.Array, radii: jax.Array, *,
@@ -158,10 +167,13 @@ def project_l1_pallas_batched(v: jax.Array, radii: jax.Array, *,
 
     The serving-bucket form of :func:`project_l1_pallas`: the batch axis is a
     PARALLEL Pallas grid dimension (one program per request) and the per-item
-    radii ride in SMEM, block-sliced by the batch grid index — the same kernel
+    radii ride in SMEM, indexed by the batch grid index — the same kernel
     bodies as the single-item version, no vmap lifting. Each program keeps its
     whole (padded) row in VMEM, so the single-block size limit
-    (``L1_KERNEL_MAX``) applies per item, not to the batch.
+    (``L1_KERNEL_MAX``) applies per item, not to the batch. The rows are laid
+    out as ``(B, 1, n_pad)`` with the batch axis squeezed out of the block, so
+    every program sees the ``(1, n_pad)`` row the single-item kernels read —
+    a block whose last two dimensions equal the array's, for any ``B``.
     """
     if method not in _THRESHOLD_KERNELS:
         raise ValueError(
@@ -172,18 +184,19 @@ def project_l1_pallas_batched(v: jax.Array, radii: jax.Array, *,
     if iters is None:
         iters = n + 2 if method == "filter" else _ITERS
     n_pad = max(_LANE, ((n + _LANE - 1) // _LANE) * _LANE)
-    v2 = jnp.zeros((b, n_pad), v.dtype).at[:, :n].set(v)
-    r = jnp.asarray(radii, v.dtype).reshape(b)
+    v2 = jnp.zeros((b, 1, n_pad), jnp.float32).at[:, 0, :n].set(v)
+    r = jnp.asarray(radii, jnp.float32).reshape(b)
+    row = pl.BlockSpec((pl.squeezed, 1, n_pad), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         functools.partial(_THRESHOLD_KERNELS[method], n_total=n, iters=iters),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
+            row,
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # all B radii
         ],
-        out_specs=pl.BlockSpec((1, n_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad), v.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(v2, r)
-    return out[:, :n]
+    return out[:, 0, :n].astype(v.dtype)

@@ -41,17 +41,9 @@ def use_pallas(y=None) -> bool:
     workload's actual placement), the default backend device otherwise —
     never on the bare ``jax.devices()[0]`` of whatever backend loaded first.
     """
-    platform = None
-    if y is not None and not isinstance(y, jax.core.Tracer):
-        devices = getattr(y, "devices", None)
-        if callable(devices):
-            try:
-                platform = next(iter(y.devices())).platform
-            except Exception:
-                platform = None
-    if platform is None:
-        platform = jax.devices()[0].platform
-    return platform == "tpu"
+    if isinstance(y, jax.Array) and not isinstance(y, jax.core.Tracer):
+        return next(iter(y.devices())).platform == "tpu"
+    return jax.devices()[0].platform == "tpu"
 
 
 def _projection(y, levels, radius, method: str, interpret: bool, force: bool):

@@ -34,8 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 DEFAULT_BLOCK_N = 256   # rows per tile (sublane axis)
 DEFAULT_BLOCK_M = 512   # cols per tile (lane axis)
@@ -87,7 +87,7 @@ def trilevel_reduce_pallas(y: jax.Array, *, block_n: int = DEFAULT_BLOCK_N,
             jax.ShapeDtypeStruct((n, m), y.dtype),
             jax.ShapeDtypeStruct((1, m), y.dtype),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -114,7 +114,7 @@ def trilevel_apply_pallas(y: jax.Array, v2: jax.Array, u1: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((c, block_n, block_m), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((c, n, m), y.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

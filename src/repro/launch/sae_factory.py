@@ -50,9 +50,12 @@ def main(argv=None) -> int:
     from repro.launch.mesh import make_host_mesh
     from repro.obs import metrics as obs_metrics
     from repro.obs import profile as obs_profile
+    from repro.runtime import compile_cache
     from repro.training import sae_factory as F
 
     import jax
+
+    compile_cache.configure()
 
     fcfg = F.SAEFactoryConfig(
         arch=args.arch, smoke=not args.full, site=args.site,

@@ -105,7 +105,7 @@ def _resolve_shard_backend(backend: str, shape, levels, names, mesh, dtype,
     try:
         ok = _dist.shardable(shape, list(levels), names, mesh, dtype,
                              batch_dims)
-    except Exception:
+    except ValueError:  # a design the schedule compiler rejects
         ok = False
     return "codegen" if ok else "jnp"
 

@@ -426,8 +426,9 @@ class ProjectionEngine:
     def _warm_dispatch_paths(self, key: GroupKey, plans) -> None:
         """Trace the first ``warm_buckets`` pow-2 dispatch paths (stack +
         executable + unstack) with dummy payloads, still on the warm pool.
-        Best-effort: a failure here resurfaces at the real dispatch, where
-        the retry/typed-error machinery handles it."""
+        A failure here fails the key's plan build: :meth:`wait_warm`
+        re-raises it, and the key's requests complete with
+        :class:`ServingError` (a later submit retries the build)."""
         shape, dtype_name, _levels, _method, shard_key = key
         if shard_key is not None or self.warm_buckets <= 0:
             return
@@ -436,18 +437,15 @@ class ProjectionEngine:
                                  jnp.asarray(0.5, dtype), None)
         ctx = timed(self._metrics.warm_s) if self._metrics \
             else contextlib.nullcontext()
-        try:
-            with ctx:
-                if "scalar" in plans:
-                    r = dummy()
-                    jax.block_until_ready(plans["scalar"](r.y, r.radius))
-                b, done = 1, 0
-                while b <= self.max_batch and done < self.warm_buckets:
-                    jax.block_until_ready(self._run_group(
-                        key, plans, [dummy() for _ in range(b)]))
-                    b, done = b * 2, done + 1
-        except Exception:
-            pass
+        with ctx:
+            if "scalar" in plans:
+                r = dummy()
+                jax.block_until_ready(plans["scalar"](r.y, r.radius))
+            b, done = 1, 0
+            while b <= self.max_batch and done < self.warm_buckets:
+                jax.block_until_ready(self._run_group(
+                    key, plans, [dummy() for _ in range(b)]))
+                b, done = b * 2, done + 1
 
     # --------------------------------------------------------- dispatcher
 

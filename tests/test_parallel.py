@@ -38,7 +38,8 @@ class TestShardedProjection:
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import bilevel_l1inf
         from repro.core.sharded import make_sharded_bilevel
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         y = jnp.asarray(rng.normal(size=(64, 96)), jnp.float32)
         fn = make_sharded_bilevel(mesh, "model")
@@ -52,15 +53,16 @@ class TestShardedProjection:
         out = _run("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from repro.core.sharded import shard_map, trilevel_project_sharded
+        from repro.core.sharded import trilevel_project_sharded
         from repro.core import multilevel_norm
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((8,), ("model",))
         rng = np.random.default_rng(1)
         y = jnp.asarray(rng.normal(size=(4, 16, 64)), jnp.float32)
         body = functools.partial(trilevel_project_sharded, axis_name="model")
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(None, None, "model"), P()),
-                       out_specs=P(None, None, "model"))
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(None, None, "model"), P()),
+                           out_specs=P(None, None, "model"), check_vma=False)
         got = jax.jit(fn)(y, jnp.float32(2.0))
         n = multilevel_norm(got, [("inf", 1), ("inf", 1), (1, 1)])
         print("NORM", float(n))
@@ -93,7 +95,8 @@ class TestShardedProjection:
         s1, m1 = step1(state1, batch)
 
         # 2x4 mesh with full sharding rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rules = SH.param_rules(mesh)
         specs = PM.param_specs(api.template(cfg), rules,
                                SH.mesh_shape_dict(mesh))
@@ -120,13 +123,15 @@ class TestShardedProjection:
         from repro.runtime import CheckpointManager
 
         mgr = CheckpointManager("{tmp_path}", keep=2)
-        mesh8 = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh8 = auto_mesh((8,), ("data",))
         x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                            NamedSharding(mesh8, P("data", None)))
         mgr.save(1, {{"x": x}})
 
         # restore onto a SHRUNK mesh (8 -> 4 data shards: elastic scale-down)
-        mesh4 = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh4 = auto_mesh((4,), ("data",))
         sh = {{"x": NamedSharding(mesh4, P("data", None))}}
         tree, _ = mgr.restore(shardings=sh)
         ok = np.allclose(np.asarray(tree["x"]), np.arange(64.0).reshape(8, 8))

@@ -234,6 +234,24 @@ class TestAutoThreading:
     def test_best_l1_method_is_generic(self):
         assert plan.best_l1_method(512) in ball.available_methods()
 
+    def test_best_l1_method_excludes_specialized_backends(self):
+        # a specialized backend eligible for the flat l1 key (as `codegen`
+        # is on a TPU) may win make_plan's shoot-out but never
+        # best_l1_method's: its callers embed the name as a theta-solver
+        plan.register_plan_backend(plan.PlanBackend(
+            name="fake_specialized",
+            available=lambda key: key.shape == (512,),
+            build=lambda key: (lambda y, radius: y)))
+        try:
+            plan.clear_cache()
+            p = plan.make_plan((512,), jnp.float32, [("1", 1)],
+                               method="auto")
+            assert "fake_specialized" in p.timings_us
+            assert plan.best_l1_method(512) in ball.available_methods()
+        finally:
+            del plan._SPECIALIZED["fake_specialized"]
+            plan.clear_cache()
+
 
 class TestCodegenBackendPlans:
     """The generated-kernel backend through the planner; the full equality

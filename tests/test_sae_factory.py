@@ -154,14 +154,18 @@ class TestMMCSInvariants:
 
 
 # ----------------------------------------------- §7.3 tables tiny regression
-# pinned on the seed container (seed=0, 160×96, 25 epochs); tolerances cover
-# BLAS-level drift, structure assertions catch method regressions outright
+# (accuracy %, column sparsity %) at seed=0, 160×96, 25 epochs, re-pinned on
+# JAX/jaxlib 0.9.0 (CPU): the numbers first pinned here came from an older
+# JAX whose random streams and CPU kernels trained the tiny SAEs to a
+# different optimum (baseline 84.4% there, 75.0% here — one 32-sample test
+# set, so 3.125% steps). Tolerances cover BLAS-level drift; the structure
+# assertions catch method regressions outright
 _PINNED = {
-    "baseline":      (84.4, 0.0),
-    "exact_l1inf":   (84.4, 2.1),
-    "bilevel_l1inf": (56.2, 65.6),
-    "bilevel_l11":   (81.2, 2.1),
-    "bilevel_l12":   (81.2, 6.2),
+    "baseline":      (75.0, 0.0),
+    "exact_l1inf":   (75.0, 1.0),
+    "bilevel_l1inf": (62.5, 58.3),
+    "bilevel_l11":   (75.0, 4.2),
+    "bilevel_l12":   (75.0, 8.3),
 }
 
 
@@ -194,6 +198,7 @@ def test_sae_tables_tiny_regression():
     # structure: only the projected methods sparsify; bi-level ℓ1,∞ dominates
     assert got["baseline"][1] == 0.0
     assert got["bilevel_l1inf"][1] > 40.0
+    assert max(got, key=lambda k: got[k][1]) == "bilevel_l1inf"
 
 
 def test_double_descent_no_rewind_ablation():

@@ -30,6 +30,10 @@ import pytest
 
 from jax.sharding import PartitionSpec as P
 
+# meshes with GSPMD (Auto) axes, as the launchers build them: JAX 0.9's
+# jax.make_mesh makes Explicit axes, which the jnp executors do not type
+from repro.launch.mesh import auto_mesh
+
 multi_device = pytest.mark.skipif(
     jax.device_count() < 8,
     reason="needs XLA_FLAGS=--xla_force_host_platform_device_count=8")
@@ -89,7 +93,7 @@ def _collective_counts(fn, *args):
 class TestShardedCodegenEquality:
     @pytest.fixture(scope="class")
     def mesh(self):
-        return jax.make_mesh((8,), ("model",))
+        return auto_mesh((8,), ("model",))
 
     @pytest.mark.parametrize("name,shape,levels,spec", DESIGNS + [PARTIAL_APPLY])
     def test_matches_jnp_body_and_unsharded(self, mesh, name, shape, levels,
@@ -226,7 +230,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import multilevel_project, multilevel_project_sharded, plan
 
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("model",))
 designs = {designs!r}
 out = {{}}
 jnp_body = {{}}

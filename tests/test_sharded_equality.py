@@ -24,6 +24,10 @@ import pytest
 
 from jax.sharding import PartitionSpec as P
 
+# meshes with GSPMD (Auto) axes, as the launchers build them: JAX 0.9's
+# jax.make_mesh makes Explicit axes, which the jnp executors do not type
+from repro.launch.mesh import auto_mesh
+
 # legitimately environment-gated: XLA device count is fixed at interpreter
 # start, so a 1-device tier-1 host CANNOT run these in-process (the subprocess
 # class below covers the same checks there); the `mesh` CI job runs them.
@@ -58,7 +62,7 @@ def _rand(shape, seed):
 class TestShardedEqualsSingleDevice:
     @pytest.fixture(scope="class")
     def mesh(self):
-        return jax.make_mesh((8,), ("model",))
+        return auto_mesh((8,), ("model",))
 
     @pytest.mark.parametrize("name,shape,levels,spec", DESIGNS)
     def test_matches_single_device(self, mesh, name, shape, levels, spec):
@@ -177,7 +181,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import (make_sharded_bilevel, make_sharded_trilevel,
                         multilevel_project, multilevel_project_sharded, plan)
 
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("model",))
 designs = {designs!r}
 out = {{}}
 for name, shape, levels, spec in designs:
