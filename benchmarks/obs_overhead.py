@@ -6,7 +6,7 @@ paths and pins the price in ``BENCH_obs_overhead.json``:
 * **serving** — one ``ProjectionEngine`` request (submit → inline drain →
   claim), ``instrument=True`` vs ``instrument=False``. The instrumented
   engine performs a handful of registry operations per request (queue-depth
-  gauge, queue/e2e/dispatch histograms, event counters); the bare engine
+  gauge, queue/e2e histograms, event counters); the bare engine
   performs none. Gate: ``overhead_on`` ≤ 1.10.
 * **training** — a cadence window of projected train steps (``_CADENCE``
   consecutive steps — what one telemetry period costs per step,

@@ -62,6 +62,7 @@ from repro.core import multilevel
 from repro.core import plan as planmod
 from repro.obs import metrics as obs_metrics
 from repro.obs.metrics import timed
+from repro.obs.profile import span
 
 # THE clock for everything time-shaped in this module — deadlines, queue
 # ages, latency accounting. A single *monotonic* source: wall-clock
@@ -179,11 +180,10 @@ class _EngineMetrics:
         self.queue_s = reg.histogram(
             "serving_queue_seconds", "submit -> dispatch-pop wait",
             labels=("key",))
+        # ends when the results are handed to the tickets: the device may
+        # still be computing them (the launch is asynchronous)
         self.e2e_s = reg.histogram(
-            "serving_e2e_seconds", "submit -> completion latency",
-            labels=("key",))
-        self.dispatch_s = reg.histogram(
-            "serving_dispatch_seconds", "one group's execute time",
+            "serving_e2e_seconds", "submit -> results handed to the tickets",
             labels=("key",))
         self.batch_size = reg.histogram(
             "serving_batch_size", "requests per dispatch",
@@ -202,12 +202,11 @@ class _EngineMetrics:
                                 "completed", "failed", "discarded")}
 
     def for_key(self, key: GroupKey) -> tuple:
-        """(queue_s, e2e_s, dispatch_s) histogram children for one key."""
+        """(queue_s, e2e_s) histogram children for one key."""
         h = self._by_key.get(key)
         if h is None:
             lbl = _key_label(key)
-            h = (self.queue_s.labels(key=lbl), self.e2e_s.labels(key=lbl),
-                 self.dispatch_s.labels(key=lbl))
+            h = (self.queue_s.labels(key=lbl), self.e2e_s.labels(key=lbl))
             self._by_key[key] = h
         return h
 
@@ -242,6 +241,10 @@ class ProjectionEngine:
                   the bare hot path — zero registry operations per request
                   (the counter dict ``stats`` is always maintained either
                   way; only histograms/gauges/labelled series are gated).
+                  Independently of it, the host spans ``serving/submit``,
+                  ``serving/dispatch`` and ``serving/launch``
+                  (:func:`repro.obs.profile.span`) record only while a
+                  profiler capture is on.
     start:        launch the background dispatcher thread. With
                   ``start=False`` the engine is synchronous: nothing runs
                   until :meth:`drain` dispatches inline (deterministic mode
@@ -301,54 +304,57 @@ class ProjectionEngine:
         already queued, and ``ValueError`` for an invalid design/backend —
         bad requests are rejected here, where the caller can handle it.
         """
-        with self._cv:
-            if self._stopping:
-                raise ServingError("engine is stopped")
-        y = jnp.asarray(y)
-        levels = planmod.canonical_levels(levels)
-        multilevel._check_levels(y.shape, levels)
-        # committed mesh-sharded tensors get their own plan key: they run
-        # through the sharded schedule executor per request, never
-        # gather-stacked with single-device traffic of the same shape
-        sharding = getattr(y, "sharding", None)
-        if not isinstance(sharding, jax.sharding.NamedSharding):
-            sharding = None
-        shard_key = planmod.canonical_sharding(sharding, y.ndim)
-        requested = self.default_method if method is None else method
-        requested = planmod.validate_backend(
-            y.shape, y.dtype, levels, requested, sharding=shard_key,
-            interpret=self.interpret,
-            radius_kind="scalar" if shard_key is not None else "batch")
-        radius = jnp.asarray(radius, y.dtype)
-        if radius.ndim != 0:
-            raise ValueError(
-                f"radius must be a scalar (one per request), got shape "
-                f"{radius.shape}")
-        key: GroupKey = (y.shape, y.dtype.name, levels, requested, shard_key)
-        abs_deadline = None if deadline is None else _now() + float(deadline)
-        m = self._metrics
-        with self._cv:
-            if self._stopping:
-                raise ServingError("engine is stopped")
-            if self._pending_count >= self.max_pending:
-                self.stats["rejected"] += 1
+        with span("serving/submit"):
+            with self._cv:
+                if self._stopping:
+                    raise ServingError("engine is stopped")
+            y = jnp.asarray(y)
+            levels = planmod.canonical_levels(levels)
+            multilevel._check_levels(y.shape, levels)
+            # committed mesh-sharded tensors get their own plan key: they
+            # run through the sharded schedule executor per request, never
+            # gather-stacked with single-device traffic of the same shape
+            sharding = getattr(y, "sharding", None)
+            if not isinstance(sharding, jax.sharding.NamedSharding):
+                sharding = None
+            shard_key = planmod.canonical_sharding(sharding, y.ndim)
+            requested = self.default_method if method is None else method
+            requested = planmod.validate_backend(
+                y.shape, y.dtype, levels, requested, sharding=shard_key,
+                interpret=self.interpret,
+                radius_kind="scalar" if shard_key is not None else "batch")
+            radius = jnp.asarray(radius, y.dtype)
+            if radius.ndim != 0:
+                raise ValueError(
+                    f"radius must be a scalar (one per request), got shape "
+                    f"{radius.shape}")
+            key: GroupKey = (y.shape, y.dtype.name, levels, requested,
+                             shard_key)
+            abs_deadline = (None if deadline is None
+                            else _now() + float(deadline))
+            m = self._metrics
+            with self._cv:
+                if self._stopping:
+                    raise ServingError("engine is stopped")
+                if self._pending_count >= self.max_pending:
+                    self.stats["rejected"] += 1
+                    if m:
+                        m.ev["rejected"].inc()
+                    raise QueueFullError(
+                        f"{self._pending_count} requests queued "
+                        f"(max_pending={self.max_pending})")
+                ticket = Ticket(self._next_ticket, key, self)
+                self._next_ticket += 1
+                self._queues.setdefault(key, []).append(
+                    _Request(ticket, y, radius, abs_deadline))
+                self._pending_count += 1
+                self.stats["submitted"] += 1
                 if m:
-                    m.ev["rejected"].inc()
-                raise QueueFullError(
-                    f"{self._pending_count} requests queued "
-                    f"(max_pending={self.max_pending})")
-            ticket = Ticket(self._next_ticket, key, self)
-            self._next_ticket += 1
-            self._queues.setdefault(key, []).append(
-                _Request(ticket, y, radius, abs_deadline))
-            self._pending_count += 1
-            self.stats["submitted"] += 1
-            if m:
-                m.ev["submitted"].inc()
-                m.queue_depth.set(self._pending_count)
-            self._ensure_plan_locked(key)
-            self._cv.notify_all()
-        return ticket
+                    m.ev["submitted"].inc()
+                    m.queue_depth.set(self._pending_count)
+                self._ensure_plan_locked(key)
+                self._cv.notify_all()
+            return ticket
 
     def prewarm(self, shape, dtype, levels, *, method: Optional[str] = None,
                 sharding=None) -> None:
@@ -474,12 +480,15 @@ class ProjectionEngine:
             if m:
                 m.queue_depth.set(self._pending_count)
                 m.inflight.set(self._inflight_reqs)
-        if m:
-            popped, (queue_h, _, _) = _now(), m.for_key(key)
-            for r in take:
-                queue_h.observe(popped - r.enqueued)
         try:
-            self._execute(key, take)
+            # from the pop to the last ticket's completion; the idle wait
+            # above stays outside
+            with span("serving/dispatch"):
+                if m:
+                    popped, (queue_h, _) = _now(), m.for_key(key)
+                    for r in take:
+                        queue_h.observe(popped - r.enqueued)
+                self._execute(key, take)
         finally:
             with self._cv:
                 self._inflight -= 1
@@ -513,9 +522,7 @@ class ProjectionEngine:
 
     def _execute(self, key: GroupKey, reqs: List[_Request]) -> None:
         m = self._metrics
-        e2e_h = dispatch_h = None
-        if m:
-            _, e2e_h, dispatch_h = m.for_key(key)
+        e2e_h = m.for_key(key)[1] if m else None
         try:
             plans = self._plans[key].result()
         except Exception as exc:
@@ -544,10 +551,11 @@ class ProjectionEngine:
         if not live:
             return
         try:
-            t0 = _now()
-            outs = self._run_group(key, plans, live)
-            if m:
-                dispatch_h.observe(_now() - t0)
+            # host time to launch the group (asynchronous: the device may
+            # still be running it when this returns). Here and not in
+            # _run_group, so the warm pool's warm-up groups do not count
+            with span("serving/launch"):
+                outs = self._run_group(key, plans, live)
         except Exception as exc:
             for r in live:
                 r.attempts += 1

@@ -323,6 +323,79 @@ class TestProfile:
         assert b"proj/" in blob, "stage scopes missing from captured trace"
 
 
+class TestSpans:
+    """``profile.span``: a TraceAnnotation plus a per-name tally while a
+    profiler session records, a shared no-op otherwise."""
+
+    def test_no_capture_records_nothing(self, reg):
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        with obs_profile.span("test/outside", step=1) as sp:
+            with obs_profile.span("test/inner"):
+                pass
+        assert sp is None
+        assert obs_profile.span("a") is obs_profile.span("b")   # shared
+        assert obs_profile.SPAN_METRIC not in reg.snapshot()
+
+    def test_nested_spans_on_two_threads_tally_their_own(self, reg,
+                                                         tmp_path):
+        def work(prefix, n):
+            for _ in range(n):
+                with obs_profile.span(f"{prefix}/outer"):
+                    with obs_profile.span(f"{prefix}/inner"):
+                        pass
+
+        with jax.profiler.trace(str(tmp_path)):
+            threads = [threading.Thread(target=work, args=(p, n))
+                       for p, n in (("t0", 5), ("t1", 3))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        fam = reg.histogram(obs_profile.SPAN_METRIC, labels=("span",))
+        counts = {c.labelvalues[0]: c.count for c in fam.children()}
+        assert counts == {"t0/outer": 5, "t0/inner": 5,
+                          "t1/outer": 3, "t1/inner": 3}
+        outer = fam.labels(span="t0/outer")
+        assert outer.sum >= fam.labels(span="t0/inner").sum > 0.0
+
+    def test_span_tally_follows_a_swapped_registry(self, reg, tmp_path):
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_profile.span("test/swap"):
+                pass
+            other = metrics.Registry()
+            prev = metrics.set_registry(other)
+            try:
+                with obs_profile.span("test/swap"):
+                    pass
+            finally:
+                metrics.set_registry(prev)
+        for r in (reg, other):
+            fam = r.histogram(obs_profile.SPAN_METRIC, labels=("span",))
+            assert fam.labels(span="test/swap").count == 1
+
+    def test_span_names_in_the_host_plane(self, reg, tmp_path):
+        from jax.profiler import ProfileData
+
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_profile.span("test/host_plane", size=7):
+                pass
+        xplane, = [f for f in obs_profile.trace_files(tmp_path)
+                   if f.name.endswith(".xplane.pb")]
+        pd = ProfileData.from_file(str(xplane))
+        names = {ev.name for plane in pd.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert "test/host_plane" in names
+
+    def test_span_records_when_the_block_raises(self, reg, tmp_path):
+        with jax.profiler.trace(str(tmp_path)):
+            with pytest.raises(RuntimeError):
+                with obs_profile.span("test/raises"):
+                    raise RuntimeError("boom")
+        fam = reg.histogram(obs_profile.SPAN_METRIC, labels=("span",))
+        assert fam.labels(span="test/raises").count == 1
+
+
 # ----------------------------------------------------- global registry wiring
 
 

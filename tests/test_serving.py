@@ -469,6 +469,53 @@ class TestEngineObservability:
         finally:
             obs_metrics.set_registry(prev)
 
+    @staticmethod
+    def _span_counts(reg):
+        from repro.obs import profile as obs_profile
+        fam = reg.snapshot().get(obs_profile.SPAN_METRIC)
+        return {v["labels"]["span"]: v["count"]
+                for v in (fam or {}).get("values", [])}
+
+    @pytest.mark.parametrize("start", [False, True])
+    def test_spans_tally_a_captured_run(self, tmp_path, start):
+        from repro.obs import metrics as obs_metrics
+        reg = obs_metrics.Registry()
+        prev = obs_metrics.set_registry(reg)
+        try:
+            eng = self._eng(start=start)
+            lv = [("inf", 1), ("1", 1)]
+            n = 6
+            eng.result(eng.submit(jnp.ones((6, 10)), lv))   # plan built
+            with jax.profiler.trace(str(tmp_path)):
+                ts = [eng.submit(jnp.full((6, 10), float(i + 1)), lv)
+                      for i in range(n)]
+                for t in ts:
+                    eng.result(t, timeout=60)
+            counts = self._span_counts(reg)
+            assert counts["serving/submit"] == n
+            assert 1 <= counts["serving/dispatch"] <= n
+            assert 1 <= counts["serving/launch"] <= counts["serving/dispatch"]
+            eng.stop()
+        finally:
+            obs_metrics.set_registry(prev)
+
+    @pytest.mark.parametrize("instrument", [True, False])
+    def test_uncaptured_run_tallies_no_span(self, instrument):
+        from repro.obs import metrics as obs_metrics
+        from repro.obs import profile as obs_profile
+        reg = obs_metrics.Registry()
+        prev = obs_metrics.set_registry(reg)
+        try:
+            eng = self._eng(instrument=instrument, start=True)
+            ts = [eng.submit(jnp.ones((8,)), [("1", 1)]) for _ in range(4)]
+            for t in ts:
+                eng.result(t, timeout=60)
+            eng.stop()
+            assert obs_profile.SPAN_METRIC not in reg.snapshot()
+            assert "serving_dispatch_seconds" not in reg.snapshot()
+        finally:
+            obs_metrics.set_registry(prev)
+
     def test_engine_source_never_reads_wall_clock(self):
         # the single-clock satellite: every engine timestamp goes through
         # the module-level ``_now`` (monotonic); wall clock is forbidden
