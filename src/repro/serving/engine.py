@@ -57,6 +57,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import multilevel
 from repro.core import plan as planmod
@@ -300,9 +301,17 @@ class ProjectionEngine:
         completes with :class:`DeadlineExceededError` instead of executing,
         and pending deadlines prioritise which key dispatches next.
 
+        ``radius`` is rounded to the payload's dtype here, on the host, and
+        stays there: the radii of a dispatched group cross to the device
+        together, as one vector, when the group's program is called (a
+        mesh-sharded request runs alone, its radius in its own call). A
+        radius given as a ``jax.Array`` is fetched to the host (a blocking
+        read; its value is unchanged).
+
         Raises :class:`QueueFullError` when ``max_pending`` requests are
-        already queued, and ``ValueError`` for an invalid design/backend —
-        bad requests are rejected here, where the caller can handle it.
+        already queued, and ``ValueError`` for an invalid design/backend or
+        a radius that is not a scalar — bad requests are rejected here,
+        where the caller can handle it.
         """
         with span("serving/submit"):
             with self._cv:
@@ -323,7 +332,7 @@ class ProjectionEngine:
                 y.shape, y.dtype, levels, requested, sharding=shard_key,
                 interpret=self.interpret,
                 radius_kind="scalar" if shard_key is not None else "batch")
-            radius = jnp.asarray(radius, y.dtype)
+            radius = np.asarray(radius, dtype=y.dtype)
             if radius.ndim != 0:
                 raise ValueError(
                     f"radius must be a scalar (one per request), got shape "
@@ -439,8 +448,10 @@ class ProjectionEngine:
         if shard_key is not None or self.warm_buckets <= 0:
             return
         dtype = jnp.dtype(dtype_name)
+        # built as submit() builds a request (host radius), so each warmed
+        # bucket is the very executable that live groups call
         dummy = lambda: _Request(None, jnp.zeros(shape, dtype),
-                                 jnp.asarray(0.5, dtype), None)
+                                 np.asarray(0.5, dtype), None)
         ctx = timed(self._metrics.warm_s) if self._metrics \
             else contextlib.nullcontext()
         with ctx:
@@ -595,17 +606,16 @@ class ProjectionEngine:
     def _fused_dispatch(self, key: GroupKey, plans, b: int):
         """One jitted executable per (key, bucket): stack → project →
         unstack fused into a single dispatch, each request's payload
-        donated individually. Without the fusion every dispatch pays
-        O(bucket) op-by-op stack/slice calls — which is exactly the
-        per-request overhead continuous batching exists to amortize."""
+        donated individually, the group's radii one ``(b,)`` vector.
+        Without the fusion every dispatch pays O(bucket) op-by-op
+        stack/slice calls — which is exactly the per-request overhead
+        continuous batching exists to amortize."""
         fn = self._fused.get((key, b))
         if fn is None:
             batch_plan = plans["batch"]
 
-            def dispatch(*args):               # b payloads then b radii
-                ys = jnp.stack(args[:b])
-                radii = jnp.stack(args[b:])
-                out = batch_plan(ys, radii)
+            def dispatch(*args):               # b payloads, then the radii
+                out = batch_plan(jnp.stack(args[:b]), args[b])
                 return tuple(out[i] for i in range(b))
 
             donate = tuple(range(b)) if self.donate else ()
@@ -627,11 +637,12 @@ class ProjectionEngine:
         dtype = jnp.dtype(dtype_name)
         # pad slots get fresh zero buffers — donation forbids handing the
         # executable the same buffer twice
-        args = ([r.y for r in live]
-                + [jnp.zeros(shape, dtype) for _ in range(pad)]
-                + [r.radius for r in live]
-                + [jnp.zeros((), dtype) for _ in range(pad)])
-        out = self._fused_dispatch(key, plans, b)(*args)
+        ys = [r.y for r in live] + [jnp.zeros(shape, dtype)
+                                    for _ in range(pad)]
+        # the host radii as one vector, pads 0: the call makes the group's
+        # one radius transfer
+        radii = np.array([r.radius for r in live] + [0] * pad, dtype)
+        out = self._fused_dispatch(key, plans, b)(*ys, radii)
         return list(out[: len(live)])
 
     # --------------------------------------------------------- completion

@@ -360,6 +360,180 @@ class TestProjectionEngine:
         np.testing.assert_allclose(out, want, atol=1e-6)
 
 
+class _Lowerings:
+    """Counts the programs JAX lowers while it is open (the listener the
+    serve benchmark's compiles-in-window check uses)."""
+
+    EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+    def __init__(self):
+        self.n = 0
+
+    def _seen(self, event, _secs, **_kw):
+        if event == self.EVENT:
+            self.n += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._seen)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._seen)
+
+
+class TestEngineHostRadius:
+    """The radius stays on the host from submit() to dispatch: a group's
+    radii cross to the device as one vector, with answers bit-identical to
+    a radius placed on the device per request."""
+
+    LV = [("inf", 1), ("1", 1)]
+    # 1 + 2**-8 + 2**-30 rounds to bfloat16 through float32 (a tie, to
+    # even: 1.0), not straight from float64 (1 + 2**-7)
+    RADII = (0.3, 1 / 3, 1 + 2 ** -8 + 2 ** -30, 2.7)
+
+    def _eng(self, **kw):
+        from repro.core import plan
+        from repro.serving import ProjectionEngine
+        plan.clear_cache()
+        kw.setdefault("method", "sort")
+        kw.setdefault("start", False)
+        return ProjectionEngine(**kw)
+
+    @staticmethod
+    def _payloads(n, seed):
+        rng = np.random.default_rng(seed)
+        return [rng.normal(size=(6, 10)).astype(np.float32) for _ in range(n)]
+
+    @staticmethod
+    def _bits(x):
+        x = np.asarray(x)
+        return x.view(np.uint16 if x.dtype.itemsize == 2 else np.uint32)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("method", ["sort", "codegen_batch"])
+    def test_answers_bit_identical_to_device_radius(self, dtype, method):
+        # singletons (sort: the scalar plan; codegen_batch: bucket 1) and a
+        # group of three padded to bucket 4, against the same plans given
+        # each radius as a device scalar of the payload's dtype
+        from repro.core import plan as planmod
+        interpret = method == "codegen_batch"
+        eng = self._eng(method=method, interpret=interpret)
+        hosts = self._payloads(len(self.RADII) + 3, seed=11)
+        dev = lambda h: jnp.asarray(h, dtype)
+        singles = [eng.project(dev(h), self.LV, r)
+                   for h, r in zip(hosts, self.RADII)]
+        group_h, group_r = hosts[len(self.RADII):], self.RADII[:3]
+        ts = [eng.submit(dev(h), self.LV, r)
+              for h, r in zip(group_h, group_r)]
+        eng.drain()
+        group = [eng.result(t) for t in ts]
+        eng.stop()
+
+        bplan = planmod.make_plan((6, 10), dtype, self.LV,
+                                  radius_kind="batch", method=method,
+                                  interpret=interpret)
+
+        def fused(*args):                    # 4 payloads, then 4 radii
+            out = bplan(jnp.stack(args[:4]), jnp.stack(args[4:]))
+            return tuple(out[i] for i in range(4))
+
+        zero = jnp.zeros((6, 10), dtype)
+        want = jax.jit(fused)(*[dev(h) for h in group_h], zero,
+                              *[jnp.asarray(r, dtype) for r in group_r],
+                              jnp.zeros((), dtype))
+        if method == "sort":
+            splan = planmod.make_plan((6, 10), dtype, self.LV, method=method)
+            want_singles = [splan(dev(h), jnp.asarray(r, dtype))
+                            for h, r in zip(hosts, self.RADII)]
+        else:
+            one = jax.jit(lambda y, r: bplan(y[None], r[None])[0])
+            want_singles = [one(dev(h), jnp.asarray(r, dtype))
+                            for h, r in zip(hosts, self.RADII)]
+        for got, w in zip(singles + group, want_singles + list(want)):
+            assert got.dtype == dtype
+            np.testing.assert_array_equal(self._bits(got), self._bits(w))
+
+    def test_radius_checks_at_submit(self):
+        eng = self._eng()
+        y = jnp.asarray(self._payloads(1, seed=12)[0])
+        for bad in (jnp.ones((3,)), np.ones((2,)), [0.5, 1.0]):
+            with pytest.raises(ValueError, match="radius must be a scalar"):
+                eng.submit(y, self.LV, bad)
+        assert eng.pending() == 0
+        # a 0-d jax.Array radius is fetched to the host: same answer
+        h = self._payloads(1, seed=13)[0]
+        a = eng.project(jnp.asarray(h), self.LV, 0.7)
+        b = eng.project(jnp.asarray(h), self.LV, jnp.asarray(0.7))
+        np.testing.assert_array_equal(self._bits(a), self._bits(b))
+        eng.stop()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_submit_queues_a_host_radius(self, dtype):
+        eng = self._eng()
+        y = jnp.asarray(self._payloads(1, seed=14)[0], dtype)
+        t = eng.submit(y, self.LV, 0.3)
+        (r,), = eng._queues.values()
+        assert isinstance(r.radius, (np.ndarray, np.generic))
+        assert not isinstance(r.radius, jax.Array)
+        assert r.radius.ndim == 0 and r.radius.dtype == jnp.dtype(dtype)
+        assert r.radius == np.asarray(jnp.asarray(0.3, dtype))
+        eng.discard(t)
+        eng.stop()
+
+    def test_padded_group_gets_zero_pad_radii(self):
+        # 3 live requests in bucket 4: one host vector of the key's dtype,
+        # the live radii first, 0 in the pad slot; live answers as alone
+        from repro.core import multilevel
+        eng = self._eng()
+        hosts = self._payloads(3, seed=15)
+        radii = self.RADII[:3]
+        calls = []
+        fused = eng._fused_dispatch
+
+        def spy(key, plans, b):
+            fn = fused(key, plans, b)
+
+            def call(*args):
+                calls.append((b, args[b:]))
+                return fn(*args)
+            return call
+
+        eng._fused_dispatch = spy
+        ts = [eng.submit(jnp.asarray(h), self.LV, r)
+              for h, r in zip(hosts, radii)]
+        eng.drain()
+        (b, (vec,)), = calls
+        assert b == 4 and isinstance(vec, np.ndarray)
+        assert vec.dtype == np.float32 and vec.shape == (4,)
+        np.testing.assert_array_equal(
+            vec, np.asarray([*radii, 0.0], np.float32))
+        for t, h, r in zip(ts, hosts, radii):
+            want = multilevel.multilevel_project(jnp.asarray(h), self.LV, r,
+                                                 method="sort")
+            np.testing.assert_allclose(eng.result(t), want, atol=1e-5)
+        eng.stop()
+
+    @pytest.mark.parametrize("method", ["sort", "codegen_batch"])
+    def test_no_lowering_after_warm_up(self, method):
+        # warm-up builds its dummies as submit() builds requests, so a
+        # size-1 and a size-2 live group call the warmed executables
+        interpret = method == "codegen_batch"
+        eng = self._eng(method=method, interpret=interpret, warm_buckets=2)
+        eng.prewarm((6, 10), jnp.float32, self.LV)
+        eng.wait_warm(timeout=120)
+        hosts = [jnp.asarray(h) for h in self._payloads(3, seed=16)]
+        jax.block_until_ready(hosts)
+        with _Lowerings() as lowered:
+            eng.result(eng.submit(hosts[0], self.LV, 0.5))
+            ts = [eng.submit(h, self.LV, 0.25) for h in hosts[1:]]
+            eng.drain()
+            outs = [eng.result(t) for t in ts]
+        assert eng.stats["dispatches"] == 2 and eng.stats["max_group"] == 2
+        assert lowered.n == 0
+        jax.block_until_ready(outs)
+        eng.stop()
+
+
 class TestEngineObservability:
     """PR-10 serving telemetry: the stats() snapshot and its accounting
     invariant, the single monotonic clock behind every deadline, and the
