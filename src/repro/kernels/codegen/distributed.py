@@ -46,7 +46,6 @@ from repro.core import schedule as sched_mod
 from repro.core.schedule import Schedule
 from repro.obs import profile as obs_profile
 
-from . import autotune_tiles
 from .lowering import (MONOIDS, _apply_call, _partial_apply_call,
                        _reduce_call, _solve_outer_vec)
 from .tiling import TilePlan, plan_tiles
@@ -96,17 +95,18 @@ def make_codegen_schedule_body(sched: Schedule,
                                axis_names: Sequence[Optional[str]], mesh,
                                dtype, *, method: str = "bisect",
                                interpret: bool = False,
-                               tile_plan: Optional[TilePlan] = None,
-                               measure: Optional[bool] = None) -> Callable:
+                               tile_plan: Optional[TilePlan] = None
+                               ) -> Callable:
     """Build the shard_map body ``(y_local, radius) -> x_local`` with the
     shard-local stages lowered through the fused Pallas kernels.
 
     ``sched`` is the GLOBAL schedule on the (padded, evenly-divisible) shape;
     the local schedule and its tile plan derive from the per-shard shape.
-    ``tile_plan`` overrides the block sizes; by default the measured
-    autotuner picks them on the local workload (``measure`` as in
-    :func:`repro.kernels.codegen.autotune_tiles`). Leading batch axes vmap
-    the batch-free body — collectives batch through vmap unchanged.
+    ``tile_plan`` overrides the block sizes; by default they are the local
+    workload's heuristic plan (``plan_tiles``), the same in every process:
+    no host-timed search, whose pick at a train step's shard (tens of µs a
+    kernel) would vary with the host. Leading batch axes vmap the
+    batch-free body — collectives batch through vmap unchanged.
 
     Gate with :func:`shardable` first; raises ``ValueError`` when the design
     has no codegen lowering on this mesh.
@@ -129,9 +129,6 @@ def make_codegen_schedule_body(sched: Schedule,
             "zero-pads and recompiles before building the body")
     lsched = sched_mod.compile_schedule(lshape[b:], levels)
     norms = [q for q, _ in levels]
-    if tile_plan is None:
-        tile_plan = autotune_tiles(lshape[b:], levels, dtype, method=method,
-                                   interpret=interpret, measure=measure)
     tp = tile_plan if tile_plan is not None else plan_tiles(lsched, dtype)
 
     # final reduce level (index L-2): mesh axes its combine spans. Levels

@@ -28,6 +28,7 @@ from repro.models import params as PM
 from repro.obs import jax_bridge
 from repro.obs import metrics as obs_metrics
 from repro.obs import profile as obs_profile
+from repro.optim import adamw
 from repro.parallel import sharding as SH
 from repro.runtime import CheckpointManager, StragglerMonitor, compile_cache
 from repro.training import init_state, make_train_step
@@ -87,11 +88,30 @@ def make_run(cfg, mesh, *, steps: int, seq: int, batch: int,
     return Run(api, tcfg, pipe, specs, step_fn)
 
 
+def state_shardings(mesh, run: Run):
+    """``NamedSharding``s of the whole train state ``{"params", "opt"}``:
+    the params' specs, both AdamW moments (and a master copy) alike, the
+    step counter replicated. ``init_state(..., shardings=...)`` builds the
+    state born sharded on them."""
+    return SH.named(mesh, {"params": run.specs,
+                           "opt": adamw.state_specs(run.specs, None,
+                                                    run.tcfg)})
+
+
+def init_opt(params, mesh, run: Run):
+    """AdamW's state for ``params`` (already on the mesh), born sharded
+    like them: one jitted init, no moment ever whole on one device."""
+    return jax.jit(lambda p: adamw.init(p, run.tcfg),
+                   out_shardings=state_shardings(mesh, run)["opt"])(params)
+
+
 def place_state(state, mesh, specs):
-    """Shard the parameters onto the mesh by their specs (the optimizer
-    state follows them through the first step)."""
-    return {"params": jax.device_put(state["params"], SH.named(mesh, specs)),
-            "opt": state["opt"]}
+    """Shard the whole state onto the mesh: the parameters by their specs,
+    the optimizer state like them (moments and master copy as their param,
+    the step counter replicated). A state born sharded is already in place
+    and stays there."""
+    specs = {"params": specs, "opt": adamw.specs_of(state["opt"], specs)}
+    return jax.device_put(state, SH.named(mesh, specs))
 
 
 def main():
@@ -149,7 +169,12 @@ def main():
             start = manifest["step"]
             print(f"[elastic restart] resuming from step {start}")
     if state is None:
-        state = init_state(cfg, tcfg, run.api, jax.random.PRNGKey(tcfg.seed))
+        # on a mesh the state is born sharded: no device ever holds it
+        # whole; on one device it is built eagerly, as it always was (the
+        # jitted init fuses each leaf's scaling, a last-bit difference)
+        state = init_state(cfg, tcfg, run.api, jax.random.PRNGKey(tcfg.seed),
+                           shardings=state_shardings(mesh, run)
+                           if mesh.size > 1 else None)
     step_hist = obs_metrics.get_registry().histogram(
         "train_step_seconds", "end-to-end wall time of one training step")
     with mesh, obs_profile.capture(args.profile_dir):

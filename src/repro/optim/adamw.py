@@ -80,23 +80,42 @@ def init(params, cfg: TrainConfig):
     return state
 
 
+def _moment_spec(sp, quantized: bool):
+    """A moment's spec: its param's, or for int8 blocks the param's for the
+    codes and the scales' trailing axis (n_blocks, rarely divisible)
+    replicated."""
+    from jax.sharding import PartitionSpec as P
+    if not quantized:
+        return sp
+    s_spec = P(*(tuple(sp)[:-1] + (None,))) if len(sp) else sp
+    return {"q": sp, "s": s_spec}
+
+
 def state_specs(param_specs_tree, params_template, cfg: TrainConfig):
     """Specs tree matching init()'s structure."""
     from jax.sharding import PartitionSpec as P
     q = cfg.moment_dtype == "int8"
-
-    def momspec(sp):
-        if not q:
-            return sp
-        # block scales: trailing dim is n_blocks (rarely divisible) -> replicate
-        s_spec = P(*(tuple(sp)[:-1] + (None,))) if len(sp) else sp
-        return {"q": sp, "s": s_spec}
-
     mom = jax.tree_util.tree_map(
-        momspec, param_specs_tree,
+        lambda sp: _moment_spec(sp, q), param_specs_tree,
         is_leaf=lambda x: isinstance(x, P))
     out = {"step": P(), "m": mom, "v": mom}
     if cfg.master_dtype and cfg.master_dtype != cfg.param_dtype:
+        out["master"] = param_specs_tree
+    return out
+
+
+def specs_of(state, param_specs_tree):
+    """Specs of an existing optimizer ``state`` (as :func:`init` built it),
+    read off its own structure: each moment and the master copy follow
+    their param's spec, the step counter is replicated."""
+    from jax.sharding import PartitionSpec as P
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    out = {"step": P()}
+    for k in ("m", "v"):
+        out[k] = jax.tree_util.tree_map(
+            lambda sp, m: _moment_spec(sp, isinstance(m, dict)),
+            param_specs_tree, state[k], is_leaf=is_spec)
+    if "master" in state:
         out["master"] = param_specs_tree
     return out
 

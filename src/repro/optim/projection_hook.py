@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.types import ProjectionSpec
 from repro.core import ball, multilevel, sharded
 from repro.core.masks import sparsity
+from repro.obs import metrics as obs_metrics
 
 
 def _path_str(path) -> str:
@@ -110,33 +111,30 @@ def _resolve_shard_backend(backend: str, shape, levels, names, mesh, dtype,
     return "codegen" if ok else "jnp"
 
 
+def _projected_layout(ndim: int, spec: ProjectionSpec):
+    """The axis order the executor sees: leading stacked axes first, then
+    the projected trailing axes, reversed when ``spec.transpose`` (an
+    involution, so the same permutation restores the layout)."""
+    batch = ndim - sum(k for _, k in spec.levels)
+    if not spec.transpose:
+        return tuple(range(ndim))
+    return tuple(range(batch)) + tuple(reversed(range(batch, ndim)))
+
+
 def _project_leaf_sharded(w, spec: ProjectionSpec, radius, method, mesh,
-                          names, backend: str = "auto"):
+                          names, backend: str):
     """Project one sharded leaf in place via the schedule executor: leading
     stacked axes are batch dims, no gather of the weight ever happens.
-    ``names`` is the canonical per-axis mesh-axis tuple (ShardingKey.spec)."""
-    need = sum(k for _, k in spec.levels)
-    batch = w.ndim - need
-    if spec.transpose:
-        # reverse the trailing (projected) axes — an involution, so the same
-        # permutation restores the layout (and permutes the spec with it)
-        perm = tuple(range(batch)) + tuple(reversed(range(batch, w.ndim)))
-        pnames = tuple(names[a] for a in perm)
-        be = _resolve_shard_backend(backend, tuple(w.shape[a] for a in perm),
-                                    spec.levels, pnames, mesh, w.dtype, batch)
-        kw = {} if be == "jnp" else dict(
-            backend="codegen", interpret=jax.default_backend() != "tpu")
-        out = sharded.multilevel_project_sharded(
-            jnp.transpose(w, perm), list(spec.levels), radius, mesh=mesh,
-            spec=P(*pnames), method=method, batch_dims=batch, **kw)
-        return jnp.transpose(out, perm)
-    be = _resolve_shard_backend(backend, tuple(w.shape), spec.levels, names,
-                                mesh, w.dtype, batch)
-    kw = {} if be == "jnp" else dict(
+    ``names`` is the canonical per-axis mesh-axis tuple (ShardingKey.spec);
+    ``backend`` the resolved body (``"jnp"`` or ``"codegen"``)."""
+    perm = _projected_layout(w.ndim, spec)
+    kw = {} if backend == "jnp" else dict(
         backend="codegen", interpret=jax.default_backend() != "tpu")
-    return sharded.multilevel_project_sharded(
-        w, list(spec.levels), radius, mesh=mesh, spec=P(*names),
-        method=method, batch_dims=batch, **kw)
+    out = sharded.multilevel_project_sharded(
+        jnp.transpose(w, perm) if spec.transpose else w, list(spec.levels),
+        radius, mesh=mesh, spec=P(*(names[a] for a in perm)), method=method,
+        batch_dims=w.ndim - sum(k for _, k in spec.levels), **kw)
+    return jnp.transpose(out, perm) if spec.transpose else out
 
 
 def _project_leaf(w, levels, radius, method, transpose=False):
@@ -191,6 +189,11 @@ def make_projection_hook(spec: ProjectionSpec | None, *, mesh=None,
     codegen kernels on TPU and keeps the jnp schedule body elsewhere;
     ``"jnp"`` / ``"codegen"`` force one — both execute the identical
     collective plan.
+
+    Each matched leaf's path is decided the first time the hook sees it
+    (its first trace) and counted once in the obs registry:
+    ``projection_leaves{path="shard_map_codegen"|"shard_map_jnp"|"vmapped"}``,
+    so a silent fall-back from the mesh-native path shows in a metric.
     """
     if spec is None or not spec.enabled:
         return lambda params, step: params
@@ -198,20 +201,39 @@ def make_projection_hook(spec: ProjectionSpec | None, *, mesh=None,
     need = sum(k for _, k in spec.levels)
     resolve = _method_resolver(spec)
     specs_by_path = _spec_table(param_specs) if mesh is not None else {}
+    counted = obs_metrics.get_registry().counter(
+        "projection_leaves", "projected leaves by the path the hook runs "
+        "them on", labels=("path",))
+    plans = {}   # leaf name -> (mesh-axis names, body) or None (vmapped)
+
+    def plan(name, w):
+        """Each leaf's path, decided (and counted) once per hook."""
+        if name not in plans:
+            skey = None
+            if mesh is not None:
+                skey = _sharded_leaf_key(mesh, specs_by_path.get(name),
+                                         w.ndim, need)
+            if skey is None:
+                plans[name], path = None, "vmapped"
+            else:
+                perm = _projected_layout(w.ndim, spec)
+                body = _resolve_shard_backend(
+                    backend, tuple(w.shape[a] for a in perm), spec.levels,
+                    tuple(skey.spec[a] for a in perm), mesh, w.dtype,
+                    w.ndim - need)
+                plans[name], path = (skey.spec, body), f"shard_map_{body}"
+            counted.labels(path=path).inc()
+        return plans[name]
 
     def project_all(params):
         def one(path, w):
             name = _path_str(path)
             if w.ndim >= need and pat.search(name):
                 method = resolve(w.shape, w.dtype)
-                skey = None
-                if mesh is not None:
-                    skey = _sharded_leaf_key(mesh, specs_by_path.get(name),
-                                             w.ndim, need)
-                if skey is not None:
+                where = plan(name, w)
+                if where is not None:
                     return _project_leaf_sharded(
-                        w, spec, spec.radius, method, mesh, skey.spec,
-                        backend=backend,
+                        w, spec, spec.radius, method, mesh, *where,
                     ).astype(w.dtype)
                 return _project_leaf(w, spec.levels, spec.radius, method,
                                      transpose=spec.transpose).astype(w.dtype)

@@ -221,9 +221,22 @@ def make_train_step(cfg: ArchConfig, tcfg: TrainConfig, api, *,
     return train_step
 
 
-def init_state(cfg: ArchConfig, tcfg: TrainConfig, api, key):
+def init_state(cfg: ArchConfig, tcfg: TrainConfig, api, key, *,
+               shardings=None):
+    """The train state ``{"params", "opt"}`` from ``key``.
+
+    ``shardings`` (a ``NamedSharding`` tree of the same structure, e.g.
+    ``launch.train.state_shardings``) makes the state born sharded: one
+    jitted init whose ``out_shardings`` place every parameter and both
+    AdamW moments on their devices, so no device ever holds the whole
+    state. Without it the state is built eagerly on the default device."""
     from repro.models import params as PM
     tpl = api.template(cfg)
-    params = PM.init_params(tpl, key, jnp.dtype(tcfg.param_dtype))
-    opt = adamw.init(params, tcfg)
-    return {"params": params, "opt": opt}
+
+    def make(key):
+        params = PM.init_params(tpl, key, jnp.dtype(tcfg.param_dtype))
+        return {"params": params, "opt": adamw.init(params, tcfg)}
+
+    if shardings is None:
+        return make(key)
+    return jax.jit(make, out_shardings=shardings)(key)

@@ -145,3 +145,23 @@ def test_sharded_codegen_body_compiles_on_4_chips(topo, shape, levels, spec):
                           _spec(shape, "float32", NamedSharding(mesh, spec)),
                           _spec((), "float32", NamedSharding(mesh, P())))
     assert "tpu_custom_call" in text
+
+
+def test_stacked_leaf_codegen_body_compiles_on_2x2(topo):
+    # the train step's mesh-native projection of stablelm-1.6b's stacked
+    # w_up on a 2x2 host: 24 layers as the batch axis, d_model over "data"
+    # (the final reduce's combine), d_ff over "model" (the gathered solve);
+    # each chip's shard is (24, 1024, 2816)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    spec = P(None, "data", "model")
+
+    def fn(y, r):
+        return multilevel_project_sharded(y, list(BI), r, mesh=mesh,
+                                          spec=spec, method="bisect",
+                                          batch_dims=1, backend="codegen")
+
+    text = _compiled_text(jax.jit(fn),
+                          _spec((24,) + W_UP, "float32",
+                                NamedSharding(mesh, spec)),
+                          _spec((), "float32", NamedSharding(mesh, P())))
+    assert text.count("tpu_custom_call") >= 3     # reduce, solve, apply
