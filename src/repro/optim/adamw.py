@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.types import TrainConfig
+from repro.obs import metrics as obs_metrics
 
 _BLOCK = 256
 
@@ -140,12 +141,19 @@ def make_leaf_update(cfg: TrainConfig, step, clip=1.0):
     shared by :func:`update` and the fused projected step
     (``optim/fused_step.py``). ``pnew`` comes back in f32 — casting to the
     param/master dtype is the CALLER's epilogue, which is exactly what lets
-    the fused step slot the projection in *before* the cast."""
+    the fused step slot the projection in *before* the cast.
+
+    Each leaf is counted once per trace in the obs registry's
+    ``adamw_leaves{path="fused"|"per_layer"}``: ``per_layer`` is the
+    layer-by-layer map that only int8 moments take."""
     lr = lr_schedule(step, cfg)
     b1, b2, eps = cfg.beta1, cfg.beta2, cfg.eps
     bc1 = 1.0 - b1 ** step.astype(jnp.float32)
     bc2 = 1.0 - b2 ** step.astype(jnp.float32)
     quant = cfg.moment_dtype == "int8"
+    counted = obs_metrics.get_registry().counter(
+        "adamw_leaves", "AdamW-updated leaves by the path they update on",
+        labels=("path",))
 
     def one(g, m, v, p):
         gf = g.astype(jnp.float32) * clip
@@ -167,11 +175,16 @@ def make_leaf_update(cfg: TrainConfig, step, clip=1.0):
         return pnew, mq, vq
 
     def one_leaf(g, m, v, p):
-        # layer-stacked tensors update one layer slice at a time (lax.map):
-        # bounds the f32 dequant/update working set to a single layer —
-        # without this the 671B/1T updates need ~70 GB of f32 temporaries.
-        if p.ndim >= 3 and p.shape[0] > 1:
+        # int8 moments: layer-stacked tensors update one layer slice at a
+        # time (lax.map), which bounds the f32 dequant/update working set to
+        # a single layer — without it the 671B/1T updates need ~70 GB of f32
+        # temporaries. Float moments have no such working set: the whole
+        # leaf is one elementwise pass that XLA aliases onto the donated
+        # state, where a map would add a loop and full-leaf copies.
+        if quant and p.ndim >= 3 and p.shape[0] > 1:
+            counted.labels(path="per_layer").inc()
             return jax.lax.map(lambda a: one(*a), (g, m, v, p))
+        counted.labels(path="fused").inc()
         return one(g, m, v, p)
 
     return one_leaf

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs.types import ProjectionSpec, TrainConfig
+from repro.obs import metrics as obs_metrics
 from repro.optim import adamw
 from repro.optim.projection_hook import (apply_projection, matched_names,
                                          project_tree, tree_sparsity)
@@ -67,6 +68,71 @@ class TestAdamW:
         g = {"w": jnp.full((8, 128), 100.0)}
         _, _, m = adamw.update(g, opt, params, tcfg)
         assert float(m["grad_norm"]) > 1000  # raw norm reported
+
+    # layer-stacked leaves: (L, d, H, hd) as wq, (L, H, hd, d) as wo, an MLP
+    # matrix and a stacked norm scale; wo and w_down take weight decay
+    _STACKED = {"wq": (4, 64, 8, 16), "wo": (4, 8, 64, 128),
+                "w_up": (4, 32, 64), "w_down": (4, 64, 128), "ln": (4, 64)}
+
+    def _stacked_case(self, moment_dtype):
+        tcfg = TrainConfig(lr=1e-2, weight_decay=0.1, grad_clip=0.0,
+                           warmup=1, total_steps=10, master_dtype="",
+                           moment_dtype=moment_dtype)
+        rng = np.random.default_rng(2)
+        draw = lambda s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+        params = {k: draw(s) for k, s in self._STACKED.items()}
+        grads = {k: draw(s) for k, s in self._STACKED.items()}
+        opt = adamw.init(params, tcfg)
+        if moment_dtype == "float32":   # moments as a few steps leave them
+            opt = {"step": jnp.asarray(3, jnp.int32),
+                   "m": {k: 0.1 * draw(s) for k, s in self._STACKED.items()},
+                   "v": {k: 0.01 * jnp.abs(draw(s))
+                         for k, s in self._STACKED.items()}}
+        return tcfg, grads, opt, params
+
+    @staticmethod
+    def _jaxpr(tcfg, grads, opt, params) -> str:
+        return str(jax.make_jaxpr(
+            lambda g, o, p: adamw.update(g, o, p, tcfg))(grads, opt, params))
+
+    def test_float_moments_update_stacked_leaves_whole(self):
+        tcfg, grads, opt, params = self._stacked_case("float32")
+        assert "scan" not in self._jaxpr(tcfg, grads, opt, params)
+        step = jax.jit(lambda g, o, p: adamw.update(g, o, p, tcfg))
+        new_p, new_o, _ = step(grads, opt, params)
+        L = self._STACKED["wq"][0]
+        at = lambda t, l: {k: x[l] for k, x in t.items()}  # noqa: E731
+        for l in range(L):
+            opt_l = {"step": opt["step"], "m": at(opt["m"], l),
+                     "v": at(opt["v"], l)}
+            ref_p, ref_o, _ = step(at(grads, l), opt_l, at(params, l))
+            for k in self._STACKED:
+                np.testing.assert_array_equal(new_p[k][l], ref_p[k], k)
+                np.testing.assert_array_equal(new_o["m"][k][l],
+                                              ref_o["m"][k], k)
+                np.testing.assert_array_equal(new_o["v"][k][l],
+                                              ref_o["v"][k], k)
+
+    def test_int8_moments_keep_per_layer_map(self):
+        tcfg, grads, opt, params = self._stacked_case("int8")
+        assert "scan" in self._jaxpr(tcfg, grads, opt, params)
+
+    @pytest.mark.parametrize("moment_dtype", ["float32", "int8"])
+    def test_adamw_leaves_counter(self, moment_dtype):
+        tcfg, grads, opt, params = self._stacked_case(moment_dtype)
+        reg = obs_metrics.Registry()
+        prev = obs_metrics.set_registry(reg)
+        try:
+            self._jaxpr(tcfg, grads, opt, params)
+        finally:
+            obs_metrics.set_registry(prev)
+        leaves = reg.counter("adamw_leaves", labels=("path",))
+        n = {path: leaves.labels(path=path).value
+             for path in ("fused", "per_layer")}
+        per_layer = (sum(len(s) >= 3 for s in self._STACKED.values())
+                     if moment_dtype == "int8" else 0)
+        assert n == {"fused": len(self._STACKED) - per_layer,
+                     "per_layer": per_layer}
 
 
 # --------------------------------------------------------------- projection

@@ -13,6 +13,7 @@ this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +22,13 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.types import TrainConfig
 from repro.core import multilevel_project_sharded
 from repro.core.schedule import compile_schedule
 from repro.kernels import codegen
 from repro.kernels.codegen.tiling import candidate_tile_plans
 from repro.kernels.l1ball import project_l1_pallas_batched
+from repro.optim import adamw
 
 BI = (("inf", 1), ("1", 1))
 TRI = (("inf", 1), ("inf", 1), ("1", 1))
@@ -165,3 +168,24 @@ def test_stacked_leaf_codegen_body_compiles_on_2x2(topo):
                                 NamedSharding(mesh, spec)),
                           _spec((), "float32", NamedSharding(mesh, P())))
     assert text.count("tpu_custom_call") >= 3     # reduce, solve, apply
+
+
+def test_adamw_updates_stacked_f32_leaves_in_place(one_chip):
+    # stablelm-1.6b's stacked wq and w_up at 4 layers, float32 moments, the
+    # state and params donated as the train step donates them: the update is
+    # one elementwise pass per leaf aliased onto its inputs — no per-layer
+    # loop, no leaf-sized copy, no leaf-sized temporary
+    shapes = {"wq": (4, 2048, 32, 64), "w_up": (4,) + W_UP}
+    tcfg = TrainConfig(master_dtype="")
+    params = {k: _spec(s, "float32", one_chip) for k, s in shapes.items()}
+    state = {"step": _spec((), "int32", one_chip),
+             "m": dict(params), "v": dict(params)}
+    fn = jax.jit(lambda g, o, p: adamw.update(g, o, p, tcfg),
+                 donate_argnums=(1, 2))
+    compiled = fn.lower(params, state, params).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    leaf = "|".join(",".join(map(str, s)) for s in shapes.values())
+    assert not re.findall(rf"f32\[(?:{leaf})\]\S* copy\(", text)
+    slice_bytes = min(np.prod(s[1:]) for s in shapes.values()) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
