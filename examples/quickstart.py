@@ -10,7 +10,7 @@ sparsity against the unconstrained baseline.
 import argparse
 import sys
 
-sys.path.insert(0, "src"); sys.path.insert(0, ".")
+sys.path.insert(0, "src")
 
 import jax
 import jax.numpy as jnp
@@ -19,9 +19,9 @@ from repro.configs import registry
 from repro.configs.types import ProjectionSpec
 from repro.core.masks import sparsity
 from repro.data import classification_synthetic
-from benchmarks.sae_tables import _accuracy, _train_fn
 from repro.models import params as PM, sae
 from repro.runtime.double_descent import double_descent
+from repro.training.sae_tables import _accuracy, _train_fn
 
 
 def main():

@@ -239,7 +239,7 @@ DEFAULT_METHOD = "sort"
 def register_l1_method(name: str, method: L1Method, *,
                        aliases: Sequence[str] = ()) -> None:
     """Register an ℓ1 θ-solver backend. One call makes it available everywhere
-    a ``method=`` kwarg exists (core, kernels dispatch, optim hook, benches)."""
+    a ``method=`` kwarg exists (core, kernels dispatch, optim hook)."""
     _L1_METHODS[name] = method
     for alias in aliases:
         _L1_ALIASES[alias] = name
@@ -302,7 +302,7 @@ def project_l1(y: jax.Array, radius: Scalar, method: str = "sort") -> jax.Array:
     return jnp.sign(y) * _soft_threshold(a, jnp.maximum(theta, 0.0)[..., None])
 
 
-# convenience aliases used by kernels/ref and benchmarks
+# convenience aliases, exported by repro.core
 project_l1_sort = functools.partial(project_l1, method="sort")
 project_l1_bisect = functools.partial(project_l1, method="bisect")
 project_l1_filter = functools.partial(project_l1, method="filter")

@@ -95,9 +95,7 @@ def multilevel_norm(x: jax.Array, levels: Sequence[Level]) -> jax.Array:
 
 
 def work_depth(shape, levels: Sequence[Level]):
-    """(work, depth) model of Prop 6.4 — the modelled sweep behind
-    ``benchmarks/projections.py::fig4_parallel`` (section ``fig4`` of
-    ``benchmarks.run``).
+    """(work, depth) model of Prop 6.4.
 
     work  = sequential element touches; depth = longest dependency chain with
     unbounded parallelism (tree reductions = log2 of the reduced extent).

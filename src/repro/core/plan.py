@@ -140,7 +140,7 @@ def register_plan_backend(backend: PlanBackend) -> None:
 
 def clear_cache() -> None:
     """Drop every cached plan, executable, and autotune verdict
-    (tests/benches), and reset the generation counters with them.
+    (tests), and reset the generation counters with them.
 
     Dropped entries count into the cumulative ``evictions`` counter; the
     hit/miss/retrace/autotune counters restart at zero so ``cache_info()``

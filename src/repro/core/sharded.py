@@ -326,8 +326,7 @@ def make_sharded_trilevel(mesh, axis_name: str, method: str = "sort"):
 def sharded_collective_bytes(shape, levels, spec, mesh,
                              itemsize: int = 4) -> dict:
     """Collective payload of this design on this mesh vs gather-and-project
-    (the generalized DESIGN.md §3 argument; used by ``benchmarks.run --only
-    sharded``)."""
+    (the generalized DESIGN.md §3 argument)."""
     if not isinstance(spec, P):
         spec = P(*spec)
     names = _spec_axis_names(spec, len(shape), mesh)

@@ -3,8 +3,7 @@
 GOLDEN REFERENCE: since the kernel code generator landed
 (``kernels/codegen``, DESIGN.md §4 "IR → Pallas lowering"), this hand-written
 kernel is no longer a planner backend — it pins the generated bi-level kernel
-in ``tests/test_codegen.py`` and baselines it in
-``benchmarks/run.py --only codegen``.
+in ``tests/test_codegen.py``.
 
 The projection is bandwidth-bound (O(1) FLOP/byte), so the kernels are tiled
 HBM→VMEM streaming passes (DESIGN.md §4):

@@ -11,8 +11,7 @@ The subsystem has three layers (DESIGN.md §4, "IR → Pallas lowering"):
   (``kernels/plan_backends.py``) and the ``ops`` dispatchers build on.
 
 Generated kernels are pinned against the hand-written golden kernels
-(``bilevel_l1inf.py`` / ``trilevel_l1infinf.py``) by ``tests/test_codegen.py``
-and benchmarked against them by ``benchmarks/run.py --only codegen``.
+(``bilevel_l1inf.py`` / ``trilevel_l1infinf.py``) by ``tests/test_codegen.py``.
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ _TUNE_REPS = 3          # interleaved min-of-rounds (the planner's protocol)
 
 
 def clear_tile_cache() -> None:
-    """Drop every cached block-size verdict (benches/tests)."""
+    """Drop every cached block-size verdict (tests)."""
     _TUNED_TILES.clear()
 
 
@@ -56,7 +55,7 @@ def autotune_tiles(shape, levels, dtype, *, method: str = "bisect",
     workload. ``measure=None`` defaults to measuring only on real hardware:
     in interpret mode block sizes change no machine behaviour (tests would
     pay the shoot-out for a meaningless verdict), so the heuristic default is
-    returned — benches that want the interpret-mode search anyway pass
+    returned — a caller that wants the interpret-mode search anyway passes
     ``measure=True``. Returns ``None`` when the design cannot be generated.
     """
     shape = tuple(int(s) for s in shape)

@@ -1,6 +1,6 @@
 """Pallas TPU flash attention — blockwise online softmax, forward AND backward.
 
-Used by the serving/prefill path on real TPUs (the dry-run and CPU tests use
+Used by the serving/prefill path on real TPUs (the CPU tests use
 the pure-jnp chunked oracle; see models/layers.py `attention_impl`) and, now
 that it carries a custom VJP, by LM *training* on TPU — gradients no longer
 fall back to the jnp oracle.

@@ -14,8 +14,7 @@ batch axis joins the Pallas grid, per-item radii in SMEM), competing only on
 
 The hand-written fused kernels (``bilevel_l1inf.py``/``trilevel_l1infinf.py``)
 are no longer registered as backends: they are the *golden references* the
-codegen equality tests pin against (``tests/test_codegen.py``) and the
-baseline of ``benchmarks/run.py --only codegen``.
+codegen equality tests pin against (``tests/test_codegen.py``).
 """
 
 from __future__ import annotations

@@ -2,8 +2,7 @@
 
 GOLDEN REFERENCE: since the kernel code generator landed
 (``kernels/codegen``), this hand-written kernel is no longer a planner
-backend — it pins the generated tri-level kernel in ``tests/test_codegen.py``
-and baselines it in ``benchmarks/run.py --only codegen``.
+backend — it pins the generated tri-level kernel in ``tests/test_codegen.py``.
 
 ``TP^{1,∞,∞}_η(Y)`` for Y ∈ R^{c,n,m} decomposes into
 

@@ -1,5 +1,5 @@
 """Production mesh builders. Functions (not module constants) so importing
-never touches jax device state (the dry-run must set XLA_FLAGS first).
+never touches jax device state (a caller may set XLA_FLAGS first).
 
 Every mesh is built with ``AxisType.Auto`` axes: the models and the train
 step shard through GSPMD (``device_put`` + ``with_sharding_constraint``),

@@ -1,4 +1,4 @@
-"""Everything the dry-run needs per (arch × shape × mesh): abstract state,
+"""Everything an AOT lowering needs per (arch × shape × mesh): abstract state,
 input ShapeDtypeStructs, shardings, and the step function to lower.
 
 ``input_specs(cfg, shape, mesh, tuning)`` follows the assignment contract:
@@ -34,7 +34,7 @@ class Tuning:
     fsdp: bool = True
     attn_impl: str = "chunked"
     projection_pattern: str = r"(w_up|w_gate)"
-    # ---- §Perf hillclimb knobs ----
+    # ---- per-arch tuning knobs ----
     ep_2d: bool = False           # experts sharded over (data, model) — no FSDP
                                   # re-gather of expert weights per microbatch
     moe_dispatch: str = ""        # "" -> cfg default; "scatter" kills the
@@ -61,7 +61,7 @@ def tuning_for(cfg: ArchConfig) -> Tuning:
 
 
 def apply_tuning(cfg: ArchConfig, tune: Tuning) -> ArchConfig:
-    """Fold hillclimb knobs into the arch config + layers.ATTN_TUNE."""
+    """Fold tuning knobs into the arch config + layers.ATTN_TUNE."""
     from repro.models import layers as L
     import jax.numpy as jnp
     L.ATTN_TUNE["chunk"] = tune.attn_chunk or 1024
@@ -153,8 +153,7 @@ def train_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, tune=None):
                                                  fsdp=tune.fsdp,
                                                  ep_2d=tune.ep_2d)
     # the projection hook gets the params' PartitionSpecs so matched sharded
-    # leaves run the schedule executor in place (no gather) — this is what the
-    # hillclimb's roofline sees as the projection's collective cost
+    # leaves run the schedule executor in place (no gather)
     step_fn = TS.make_train_step(cfg, tcfg, api, impl=tune.attn_impl,
                                  n_groups=n_groups, act_spec=act_spec,
                                  logits_spec=logits_spec, mesh=mesh,
@@ -242,8 +241,8 @@ def sae_factory_cell(d_model: int, mesh, *, expansion: int = 8,
     Activation rows stream in (n_micro, mb, d_model); the encoder weight
     ((d_model, expansion*d_model), 'ffn'-sharded over 'model') is projected
     onto the bi-level ball every step — through the §3 mesh executor when its
-    trailing axis is sharded, so the dry-run/roofline sees the factory's real
-    collective cost at production batch sizes. ``heads > 1`` is the §6
+    trailing axis is sharded, so the lowered cell carries the factory's real
+    collectives at production batch sizes. ``heads > 1`` is the §6
     head-structured variant: a 3-D encoder (d_model, heads, d_dict//heads)
     projected onto the tri-level ℓ1,∞,∞ ball.
     """

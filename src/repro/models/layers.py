@@ -5,7 +5,7 @@ implementations selected by ``impl``:
 
   * "naive"   — materializes S×S logits (tiny smoke tests only)
   * "chunked" — lax.scan online softmax over KV chunks: memory-bounded, pure
-                jnp, the dry-run/default path (flash semantics, XLA-lowered)
+                jnp, the default path (flash semantics, XLA-lowered)
   * "pallas"  — repro.kernels flash kernel (real TPUs)
 
 All attention math accumulates in f32 regardless of compute dtype.
@@ -26,8 +26,8 @@ from .params import ParamDef
 
 _NEG = -1e30
 
-# hillclimb knobs for the chunked attention path (set by launch/specs.py
-# before lowering; trace-time constants, see EXPERIMENTS.md §Perf)
+# tuning knobs for the chunked attention path (set by launch/specs.py
+# before lowering; trace-time constants)
 ATTN_TUNE = {"chunk": 1024, "probs_dtype": None}  # None -> f32 probs
 
 
@@ -248,7 +248,7 @@ def moe_apply(p, x, cfg: MoEConfig, *, n_groups: int, act="silu"):
     Tokens are split into ``n_groups`` groups (≈ one per data shard); dispatch
     is per-group so the position-cumsum never crosses shards. ``einsum``
     dispatch is the robust GSPMD path; ``scatter`` (cfg.dispatch) is the
-    gather-based variant used by the §Perf hillclimb.
+    gather-based variant.
     """
     tkns, m = x.shape
     e, k = cfg.n_experts, cfg.top_k

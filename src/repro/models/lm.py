@@ -2,8 +2,8 @@
 chameleon (dense, GQA, SWA, qk-norm) and deepseek-v3 / kimi-k2 (MLA + MoE).
 
 Layers are *stacked* (leading 'layers' axis) and applied with lax.scan — one
-layer body in the HLO regardless of depth (critical for 512-device dry-run
-compile times). MoE models have two stacks: the leading dense layers and the
+layer body in the HLO regardless of depth (compile time does not grow with
+the layer count). MoE models have two stacks: the leading dense layers and the
 MoE layers.
 
 Three entry points:
@@ -289,7 +289,7 @@ def forward(params, tokens, cfg: ArchConfig, *, impl="chunked", n_groups=1,
     ``act_spec``: PartitionSpec for (B, S, D) activations. The embedding
     gather otherwise inherits the table's FSDP sharding (batch replicated!) —
     constraining here pins activations to batch-sharded layout for the whole
-    stack (see EXPERIMENTS.md §Perf, stablelm iteration 0).
+    stack.
 
     ``collect``: None | "resid" | "mlp" — when set, also return the per-layer
     activations stacked on a leading layer axis, shape (L, B, S, D): the

@@ -78,7 +78,7 @@ def init_params(template, key: jax.Array, dtype=jnp.float32):
 
 
 def abstract_params(template, dtype=jnp.float32):
-    """ShapeDtypeStruct tree (for dry-run lowering — no allocation)."""
+    """ShapeDtypeStruct tree (for AOT lowering — no allocation)."""
     return _tree_map(lambda pd: jax.ShapeDtypeStruct(pd.shape, dtype), template)
 
 

@@ -11,8 +11,7 @@ process-global registry whenever it lands.
 The bridge is **gated off by default** and the gate is *trace-time static*:
 ``report(...)`` inside a function traced while the bridge is disabled
 lowers to nothing at all — the jitted program is bit-identical to the
-un-instrumented one (the ≤2% overhead-off gate in
-``benchmarks/obs_overhead.py`` pins exactly this). Enabling the bridge and
+un-instrumented one (``tests/test_obs.py`` pins this at HLO level). Enabling the bridge and
 re-tracing (new shapes, or an explicit cache clear) is what turns the
 telemetry on; the ``REPRO_OBS_BRIDGE=1`` env var enables it from launch.
 

@@ -272,7 +272,7 @@ class Registry:
                                    buckets)
 
     def clear(self) -> None:
-        """Drop every family (tests / bench isolation)."""
+        """Drop every family (test isolation)."""
         with self._lock:
             self._families.clear()
 
@@ -367,9 +367,8 @@ def timed(hist, **labels):
         child.observe(time.perf_counter() - t0)
 
 
-# process-global default registry — what the serving engine, the planner,
-# the training telemetry, and the benchmarks all record into unless handed
-# an explicit one
+# process-global default registry — what the serving engine, the planner
+# and the training telemetry all record into unless handed an explicit one
 REGISTRY = Registry()
 
 

@@ -3,7 +3,7 @@ the host spans.
 
 Three consumers:
 
-* launchers and benchmarks wrap a region in :func:`capture` — a thin,
+* launchers wrap a region in :func:`capture` — a thin,
   None-tolerant wrapper over ``jax.profiler.trace`` (pass the launcher's
   ``--profile-dir`` straight through; empty/None disables cleanly);
 * the schedule executors (``core/schedule.py`` jnp path,

@@ -4,7 +4,7 @@ The quantized-moment mode is the distributed-optimization trick that makes
 Adam states for the 671B/1T MoEs fit a v5e pod: m and v are stored as int8
 with a float32 scale per 256-element block of the trailing axis (linear
 symmetric for m, linear positive for v). Dequant → f32 update → requant every
-step. See EXPERIMENTS.md §Dry-run memory table for the effect.
+step.
 """
 
 from __future__ import annotations

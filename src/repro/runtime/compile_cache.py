@@ -2,8 +2,7 @@
 
 ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and this
 module sets nothing. Otherwise the entry points (``chip_smoke.py``,
-``repro.launch.train``, ``repro.launch.sae_factory``, ``benchmarks.run``)
-call :func:`configure`, which points the cache at ``<checkout>/.jax_cache``:
+``repro.launch.train``, ``repro.launch.sae_factory``) call :func:`configure`, which points the cache at ``<checkout>/.jax_cache``:
 one fixed path, so a second run of the same program reads what the first
 one compiled, where a temporary or per-process directory would never hit.
 """

@@ -1,8 +1,6 @@
 """repro.serving — the continuous-batching projection engine (async
-submit/poll, DESIGN.md §5), the legacy flush()-driven projection service,
-and LM prefill/decode serve steps."""
+submit/poll, DESIGN.md §5) and LM prefill/decode serve steps."""
 from .engine import (DeadlineExceededError, ProjectionEngine,  # noqa: F401
                      QueueFullError, ServingError, Ticket,
                      UnknownTicketError)
 from .lm import generate, make_decode_step, make_prefill  # noqa: F401
-from .projection_service import ProjectionService  # noqa: F401

@@ -1,13 +1,10 @@
 """Continuous-batching projection engine: async submit/poll with latency SLOs.
 
 The engine is the production serving tier over the projection planner
-(DESIGN.md §5). It replaces the bucket-and-wait flow of
-:class:`~repro.serving.projection_service.ProjectionService` — where a
-request waits until its group is explicitly ``flush()``-ed — with
-**continuous batching**: a background dispatcher pops *every* request
-pending for one plan key the moment that key's plan is ready, so a request
-joins the next in-flight dispatch for its key instead of waiting for a
-bucket to fill or a caller to flush.
+(DESIGN.md §5). It serves by **continuous batching**: a background
+dispatcher pops *every* request pending for one plan key the moment that
+key's plan is ready, so a request joins the next in-flight dispatch for its
+key instead of waiting for a bucket to fill or a caller to flush.
 
 Four mechanisms make the latency profile (DESIGN.md §5 derives the model):
 
@@ -72,9 +69,8 @@ from repro.obs.profile import span
 # tests/test_serving.py). Tests monkeypatch this one name to fake time.
 _now = time.monotonic
 
-# (shape, dtype name, canonical levels, canonical method, sharding key) —
-# same grouping rule as ProjectionService: requests share a dispatch iff
-# they share a planner executable
+# (shape, dtype name, canonical levels, canonical method, sharding key):
+# requests share a dispatch iff they share a planner executable
 GroupKey = Tuple[Tuple[int, ...], str, Tuple[Tuple[str, int], ...], str,
                  object]
 
@@ -165,8 +161,7 @@ class _EngineMetrics:
     All series live in the process-global obs registry (labelled by plan
     key where it matters), so one scrape sees every engine in the process.
     ``instrument=False`` engines skip this object entirely — the bare hot
-    path performs zero registry operations (the ≤2% overhead-off gate in
-    benchmarks/obs_overhead.py measures exactly that configuration).
+    path performs zero registry operations.
     """
 
     def __init__(self):
@@ -249,7 +244,7 @@ class ProjectionEngine:
     start:        launch the background dispatcher thread. With
                   ``start=False`` the engine is synchronous: nothing runs
                   until :meth:`drain` dispatches inline (deterministic mode
-                  for tests and benchmarks).
+                  for tests).
     """
 
     def __init__(self, *, method: str = planmod.AUTO, max_batch: int = 64,

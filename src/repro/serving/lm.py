@@ -1,5 +1,5 @@
 """LM serving helpers: batched prefill + single-token decode steps (the
-``serve_step`` lowered by the decode_* dry-run cells) and eager greedy
+``serve_step`` of the decode_* cells) and eager greedy
 generation, used by examples/serve_lm.py. The projection serving engine —
 the async continuous-batching tier — lives in ``serving/engine.py``.
 

@@ -17,9 +17,8 @@ Three stages, each reusing the stack the previous PRs built:
    every layer, with sharded leaves routed through the mesh executor
    (forced 8-device CPU mesh in CI; 1B–671B configs on real meshes).
 
-``benchmarks/sae_factory.py`` drives stages 1–3 at miniature scale plus the
-paper's §7.3 accuracy-vs-sparsity tables into ``BENCH_sae_factory.json``;
-``launch/sae_factory.py`` is the CLI. Everything here is deterministic given
+``training/sae_tables.py`` runs the paper's §7.3 accuracy-vs-sparsity
+tables; ``launch/sae_factory.py`` is the CLI. Everything here is deterministic given
 (arch, seeds): the data cursor is the step index, inits are PRNGKey-seeded.
 """
 

@@ -74,8 +74,7 @@ def run_dataset(name, x, y, *, radius, epochs=150, lr=3e-3, seed=0,
 
     ``rewind=False`` runs the no-rewind double-descent ablation (descent #2
     fine-tunes the projected weights); ``only`` restricts to a subset of
-    method names. The SAE-factory bench reuses this with ``prefix=
-    "sae_factory"`` so its artifact rows don't collide with BENCH_sae_tables.
+    method names.
     """
     cfg_base = registry.get_arch("sae-paper")
     import dataclasses

@@ -94,10 +94,9 @@ def make_train_step(cfg: ArchConfig, tcfg: TrainConfig, api, *,
     ``train_projection_seconds`` histograms — the projection-time share of a
     step). Ordered callbacks serialize with the computation on EVERY step
     (they cannot ride the cadence cond), so marks are an opt-in deep-dive
-    tool, priced separately by ``benchmarks/obs_overhead.py``. All of it
-    rides :mod:`repro.obs.jax_bridge`, whose gate is trace-time static:
-    with the bridge disabled the lowered step is bit-identical to
-    ``telemetry_every=0`` (the overhead-off gate pins this).
+    tool. All of it rides :mod:`repro.obs.jax_bridge`, whose gate is
+    trace-time static: with the bridge disabled the lowered step is
+    bit-identical to ``telemetry_every=0``.
     """
     compute_dtype = jnp.dtype(tcfg.compute_dtype)
     if loss_fn is None:

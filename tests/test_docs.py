@@ -20,7 +20,6 @@ DOCTEST_MODULES = ["repro.core.plan", "repro.obs.metrics"]
 # modules doctested opportunistically (no examples required yet)
 DOCTEST_OPTIONAL = ["repro.core.ball", "repro.core.multilevel",
                     "repro.core.bilevel", "repro.serving.engine",
-                    "repro.serving.projection_service",
                     "repro.obs.jax_bridge", "repro.obs.profile"]
 
 _FENCE = re.compile(r"^```python\s*$(.*?)^```\s*$", re.M | re.S)

@@ -205,8 +205,8 @@ class TestBridge:
 
     def test_disabled_gate_is_trace_time_static(self, reg):
         """With the bridge off at trace time the lowered program is
-        bit-identical to one with no report() at all — the overhead-off
-        claim in benchmarks/obs_overhead.py, pinned at HLO level."""
+        bit-identical to one with no report() at all — telemetry compiled
+        out costs nothing, pinned at HLO level."""
 
         def plain(x):
             return x * 2.0

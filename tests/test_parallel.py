@@ -1,6 +1,6 @@
 """Mesh-parallel behaviour — runs in subprocesses with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 so the main test process
-keeps its single device (the dry-run flag must NOT be set globally)."""
+keeps its single device (the device-count flag must NOT be set globally)."""
 
 import json
 import subprocess
@@ -141,33 +141,6 @@ class TestShardedProjection:
 
 
 class TestRooflineParser:
-    def test_collective_and_dot_parsing(self):
-        from repro.roofline import hlo_parse
-        hlo = """
-HloModule test
-
-%body (p: (s32[], f32[16,16])) -> (s32[], f32[16,16]) {
-  %p = (s32[], f32[16,16]) parameter(0)
-  %i = s32[] get-tuple-element(%p), index=0
-  %x = f32[16,16] get-tuple-element(%p), index=1
-  %d = f32[16,16]{1,0} dot(%x, %x), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  %ar = f32[16,16] all-reduce(%d), replica_groups={}, to_apply=%add
-  ROOT %t = (s32[], f32[16,16]) tuple(%i, %ar)
-}
-
-ENTRY %main (a: f32[16,16]) -> f32[16,16] {
-  %a = f32[16,16] parameter(0)
-  %init = (s32[], f32[16,16]) tuple(%c, %a)
-  %w = (s32[], f32[16,16]) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"5"}}
-  ROOT %out = f32[16,16] get-tuple-element(%w), index=1
-}
-"""
-        costs = hlo_parse.analyze_text(hlo)
-        # dot: 2*16*16*16 = 8192 flops x 5 trips
-        assert costs.flops == pytest.approx(8192 * 5)
-        # all-reduce: 16*16*4 bytes * 2 (ring) * 5 trips
-        assert costs.coll_bytes == pytest.approx(1024 * 2 * 5)
-
     def test_cell_skip_rules(self):
         from repro.configs import registry
         from repro.configs.types import SHAPES

@@ -4,7 +4,7 @@
   fallback in the seed container): harvest round-trip — shapes/dtypes/layer
   selection survive shard-write → ``DataPipeline``-read — and the MMCS
   invariants (self-similarity, permutation/sign invariance, symmetry).
-* a deterministic tiny-config regression for ``benchmarks/sae_tables`` that
+* a deterministic tiny-config regression for ``repro.training.sae_tables`` that
   pins test accuracy and first-layer column sparsity for all 5 methods.
 * a miniature end-to-end factory run (harvest → projected SAE training →
   MMCS) asserting the per-step constraint actually holds on the result.
@@ -172,13 +172,13 @@ _PINNED = {
 @pytest.mark.slow
 def test_sae_tables_tiny_regression_slow():
     # the nightly (-m "") run covers the committed bench config itself
-    from benchmarks.sae_tables import tables
+    from repro.training.sae_tables import tables
     rows = tables(full=False)
     assert len(rows) == 10
 
 
 def test_sae_tables_tiny_regression():
-    from benchmarks.sae_tables import run_dataset
+    from repro.training.sae_tables import run_dataset
     from repro.data import classification_synthetic
 
     x, y, _ = classification_synthetic(n_samples=160, n_features=96,
@@ -202,7 +202,7 @@ def test_sae_tables_tiny_regression():
 
 
 def test_double_descent_no_rewind_ablation():
-    from benchmarks.sae_tables import run_dataset
+    from repro.training.sae_tables import run_dataset
     from repro.data import classification_synthetic
 
     x, y, _ = classification_synthetic(n_samples=120, n_features=64,

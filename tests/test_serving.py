@@ -1,5 +1,5 @@
-"""Serving tier: LM generation, flush()-batched service, and the
-continuous-batching ProjectionEngine (typed failures, donation, batching)."""
+"""Serving tier: LM generation and the continuous-batching
+ProjectionEngine (typed failures, donation, batching)."""
 
 import dataclasses
 import time
@@ -82,116 +82,6 @@ class TestGenerate:
                                    rtol=5e-3, atol=5e-3)
 
 
-# ------------------------------------------------------- projection service
-class TestProjectionService:
-    """Plan-batched heterogeneous projection requests (serving/projection_service)."""
-
-    def _svc(self, method="sort"):
-        from repro.core import plan
-        from repro.serving import ProjectionService
-        plan.clear_cache()
-        return ProjectionService(method=method)
-
-    def test_heterogeneous_requests_grouped_by_plan_key(self):
-        from repro.core import multilevel
-        svc = self._svc()
-        rng = np.random.default_rng(0)
-        mats = [jnp.asarray(rng.normal(size=(6, 10)), jnp.float32) for _ in range(3)]
-        vec = jnp.asarray(rng.normal(size=(40,)), jnp.float32)
-        lv2, lv1 = [("inf", 1), ("1", 1)], [("1", 1)]
-        tickets = [svc.submit(m, lv2, radius=r) for m, r in zip(mats, (0.5, 1.0, 2.0))]
-        tv = svc.submit(vec, lv1, radius=1.0)
-        assert svc.pending() == 4
-        svc.flush()
-        # 3 same-key matrices batched into ONE vmap'd dispatch + 1 singleton
-        assert svc.stats["executed_batches"] == 2
-        assert svc.stats["batched_requests"] == 3
-        assert svc.pending() == 0
-        for t, m, r in zip(tickets, mats, (0.5, 1.0, 2.0)):
-            want = multilevel.multilevel_project(m, lv2, r, method="sort")
-            np.testing.assert_allclose(svc.result(t), want, atol=1e-5)
-        from repro.core import ball
-        np.testing.assert_allclose(svc.result(tv),
-                                   ball.project_l1(vec, 1.0), atol=1e-5)
-
-    def test_results_keyed_by_ticket_not_order(self):
-        from repro.core import ball
-        svc = self._svc()
-        a = jnp.asarray(np.random.default_rng(1).normal(size=(8,)), jnp.float32)
-        b = jnp.asarray(np.random.default_rng(2).normal(size=(8,)), jnp.float32)
-        ta = svc.submit(a, [("1", 1)], 1.0)
-        tb = svc.submit(b, [("1", 1)], 1.0)
-        svc.flush()
-        np.testing.assert_allclose(svc.result(tb), ball.project_l1(b, 1.0),
-                                   atol=1e-6)
-        np.testing.assert_allclose(svc.result(ta), ball.project_l1(a, 1.0),
-                                   atol=1e-6)
-
-    def test_project_convenience_and_auto(self):
-        from repro.core import multilevel
-        svc = self._svc(method="auto")
-        y = jnp.asarray(np.random.default_rng(3).normal(size=(5, 9)), jnp.float32)
-        lv = [("inf", 1), ("1", 1)]
-        got = svc.project(y, lv, 1.5)
-        want = multilevel.multilevel_project(y, lv, 1.5, method="sort")
-        np.testing.assert_allclose(got, want, atol=1e-5)
-
-    def test_unflushed_ticket_raises(self):
-        svc = self._svc()
-        t = svc.submit(jnp.ones((4,)), [("1", 1)], 1.0)
-        with pytest.raises(KeyError):
-            svc.result(t)  # submitted but never flushed
-
-    def test_bad_request_rejected_at_submit_not_flush(self):
-        # an invalid request must fail at submit() — raising inside flush()
-        # would abort the whole batch and wedge the queue
-        from repro.core import ball
-        svc = self._svc()
-        good = jnp.asarray(np.random.default_rng(4).normal(size=(4,)), jnp.float32)
-        t = svc.submit(good, [("1", 1)], 1.0)
-        with pytest.raises(ValueError):  # 2 levels cover 2 axes, tensor has 3
-            svc.submit(jnp.ones((4, 6, 2)), [("inf", 1), ("1", 1)], 1.0)
-        with pytest.raises(ValueError):  # unknown backend name
-            svc.submit(good, [("1", 1)], 1.0, method="nope")
-        with pytest.raises(ValueError):  # non-scalar radius
-            svc.submit(good, [("1", 1)], jnp.ones((3,)))
-        assert svc.pending() == 1
-        svc.flush()
-        assert svc.pending() == 0
-        np.testing.assert_allclose(svc.result(t), ball.project_l1(good, 1.0),
-                                   atol=1e-6)
-
-    def test_group_sizes_bucket_to_one_trace(self):
-        # group sizes 3 and 4 share the pow-2 bucket -> ONE trace of the
-        # batch executable, not one per distinct group size
-        from repro.core import plan as planmod
-        svc = self._svc()
-        rng = np.random.default_rng(6)
-        lv = [("1", 1)]
-        for size in (3, 4):
-            for _ in range(size):
-                svc.submit(jnp.asarray(rng.normal(size=(16,)), jnp.float32),
-                           lv, 1.0)
-            svc.flush()
-        p = planmod.make_plan((16,), jnp.float32, lv, radius_kind="batch",
-                              method="sort")
-        assert p.trace_count == 1
-
-    def test_method_aliases_share_a_batch(self):
-        # michelot is an alias of filter: both requests fold to one group
-        svc = self._svc(method="filter")
-        rng = np.random.default_rng(5)
-        a = jnp.asarray(rng.normal(size=(3, 7)), jnp.float32)
-        b = jnp.asarray(rng.normal(size=(3, 7)), jnp.float32)
-        lv = [("inf", 1), ("1", 1)]
-        ta = svc.submit(a, lv, 1.0)
-        tb = svc.submit(b, lv, 1.0, method="michelot")
-        svc.flush()
-        assert svc.stats["executed_batches"] == 1
-        assert svc.stats["batched_requests"] == 2
-        svc.result(ta), svc.result(tb)
-
-
 # ------------------------------------------------------- projection engine
 class TestProjectionEngine:
     """Continuous-batching async engine (serving/engine): typed failure
@@ -224,6 +114,79 @@ class TestProjectionEngine:
         assert eng.stats["batched_requests"] == 5
         for t, want in zip(ts, wants):
             np.testing.assert_allclose(eng.result(t), want, atol=1e-5)
+        eng.stop()
+
+    @pytest.mark.parametrize("engine_method,requests,dispatches", [
+        # three same-key matrices batch into one dispatch, the ℓ1 vector
+        # (another plan key) into a second
+        ("sort", [((6, 10), [("inf", 1), ("1", 1)], None, 0.5),
+                  ((6, 10), [("inf", 1), ("1", 1)], None, 1.0),
+                  ((6, 10), [("inf", 1), ("1", 1)], None, 2.0),
+                  ((40,), [("1", 1)], None, 1.0)], 2),
+        # michelot is an alias of filter: both requests fold to one group
+        ("filter", [((3, 7), [("inf", 1), ("1", 1)], None, 1.0),
+                    ((3, 7), [("inf", 1), ("1", 1)], "michelot", 1.0)], 1),
+    ], ids=["heterogeneous_keys", "method_aliases"])
+    def test_dispatches_follow_plan_keys(self, engine_method, requests,
+                                         dispatches):
+        from repro.core import multilevel
+        eng = self._eng(method=engine_method)
+        rng = np.random.default_rng(0)
+        ys = [jnp.asarray(rng.normal(size=shape), jnp.float32)
+              for shape, *_ in requests]
+        wants = [multilevel.multilevel_project(y, lv, r, method="sort")
+                 for y, (_, lv, _, r) in zip(ys, requests)]
+        ts = [eng.submit(y, lv, radius=r, method=m)
+              for y, (_, lv, m, r) in zip(ys, requests)]
+        assert eng.pending() == len(requests)
+        eng.drain()
+        assert eng.pending() == 0
+        assert eng.stats_snapshot()["dispatches"] == dispatches
+        for t, want in zip(ts, wants):
+            np.testing.assert_allclose(eng.result(t), want, atol=1e-5)
+        eng.stop()
+
+    def test_results_keyed_by_ticket_not_order(self):
+        from repro.core import ball
+        eng = self._eng()
+        rng = np.random.default_rng(1)
+        a, b = (jnp.asarray(rng.normal(size=(8,)), jnp.float32)
+                for _ in range(2))
+        want_a, want_b = ball.project_l1(a, 1.0), ball.project_l1(b, 1.0)
+        ta = eng.submit(a, [("1", 1)], 1.0)
+        tb = eng.submit(b, [("1", 1)], 1.0)
+        eng.drain()
+        np.testing.assert_allclose(eng.result(tb), want_b, atol=1e-6)
+        np.testing.assert_allclose(eng.result(ta), want_a, atol=1e-6)
+        eng.stop()
+
+    @pytest.mark.parametrize("method", ["auto", "filter"])
+    def test_project_matches_sort_reference(self, method):
+        from repro.core import multilevel
+        eng = self._eng(method=method)
+        y = jnp.asarray(np.random.default_rng(3).normal(size=(5, 9)),
+                        jnp.float32)
+        lv = [("inf", 1), ("1", 1)]
+        want = multilevel.multilevel_project(y, lv, 1.5, method="sort")
+        np.testing.assert_allclose(eng.project(y, lv, 1.5), want, atol=1e-5)
+        eng.stop()
+
+    def test_group_sizes_share_a_bucket(self):
+        # group sizes 3 and 4 pad to the same pow-2 bucket: the second
+        # group calls the executable the first one lowered
+        eng = self._eng()
+        rng = np.random.default_rng(6)
+        lv = [("1", 1)]
+        for size in (3, 4):
+            ys = [jnp.asarray(rng.normal(size=(16,)), jnp.float32)
+                  for _ in range(size)]
+            jax.block_until_ready(ys)
+            with _Lowerings() as lowered:
+                ts = [eng.submit(y, lv, 1.0) for y in ys]
+                eng.drain()
+                jax.block_until_ready([eng.result(t) for t in ts])
+        assert lowered.n == 0
+        assert eng.stats_snapshot()["dispatches"] == 2
         eng.stop()
 
     def test_threaded_submit_poll_result(self):

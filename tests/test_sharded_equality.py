@@ -148,20 +148,21 @@ class TestShardedEqualsSingleDevice:
             multilevel_project(ys, BILEVEL, 2.0, method="auto"), want,
             atol=1e-4)
 
-    def test_service_groups_by_sharding(self, mesh):
+    def test_engine_groups_by_sharding(self, mesh):
         from jax.sharding import NamedSharding
-        from repro.serving import ProjectionService
+        from repro.serving import ProjectionEngine
         from repro.core import multilevel_project
         y = _rand((32, 64), seed=5)
         ys = jax.device_put(y, NamedSharding(mesh, P(None, "model")))
-        svc = ProjectionService(method="sort")
-        t1 = svc.submit(y, BILEVEL, radius=1.0)
-        t2 = svc.submit(ys, BILEVEL, radius=1.0)  # same shape, own plan key
-        svc.flush()
-        assert svc.stats["executed_batches"] == 2
         want = multilevel_project(y, BILEVEL, 1.0)
-        np.testing.assert_allclose(svc.result(t1), want, atol=1e-5)
-        np.testing.assert_allclose(svc.result(t2), want, atol=1e-4)
+        eng = ProjectionEngine(method="sort", start=False)
+        t1 = eng.submit(y, BILEVEL, radius=1.0)
+        t2 = eng.submit(ys, BILEVEL, radius=1.0)  # same shape, own plan key
+        eng.drain()
+        assert eng.stats_snapshot()["dispatches"] == 2
+        np.testing.assert_allclose(eng.result(t1), want, atol=1e-5)
+        np.testing.assert_allclose(eng.result(t2), want, atol=1e-4)
+        eng.stop()
 
 
 class TestShardedEqualitySubprocess:
